@@ -25,9 +25,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Union
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 
 Axis = Union[None, str, tuple[str, ...]]
 
@@ -48,8 +46,16 @@ RULES: dict[str, Axis] = {
 }
 
 
-def _mesh():
-    return compat.get_abstract_mesh()
+def current_mesh():
+    """The mesh governing the current trace, or None outside any mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
+
+
+def auto_axis_names(mesh) -> set:
+    """Axis names currently in Auto mode (usable in sharding constraints)."""
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
 
 
 def _present_axes(mesh, axis: Axis) -> Optional[Axis]:
@@ -59,7 +65,7 @@ def _present_axes(mesh, axis: Axis) -> Optional[Axis]:
     if axis is None:
         return None
     names = (axis,) if isinstance(axis, str) else axis
-    auto = compat.auto_axis_names(mesh)
+    auto = auto_axis_names(mesh)
     kept = tuple(a for a in names
                  if a in mesh.axis_names and mesh.shape[a] > 1 and a in auto)
     if not kept:
@@ -80,7 +86,7 @@ def _axis_size(mesh, axis: Axis) -> int:
 def spec_for(logical: Sequence[Optional[str]], dims: Optional[Sequence[int]] = None,
              rules: Optional[dict] = None) -> P:
     """PartitionSpec from logical names, with divisibility fallback."""
-    mesh = _mesh()
+    mesh = current_mesh()
     rules = rules or RULES
     out = []
     for i, name in enumerate(logical):
@@ -105,7 +111,7 @@ def spec_for(logical: Sequence[Optional[str]], dims: Optional[Sequence[int]] = N
 
 def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     """with_sharding_constraint by logical axis names; no-op without a mesh."""
-    mesh = _mesh()
+    mesh = current_mesh()
     if mesh is None:
         return x
     spec = spec_for(logical, dims=x.shape)
@@ -161,7 +167,7 @@ def _match(path: str) -> Optional[tuple[Optional[str], ...]]:
 
 def params_pspecs(params) -> "jax.tree_util.PyTreeDef":
     """PartitionSpec pytree matching ``params`` (call under a mesh context)."""
-    mesh = _mesh()
+    mesh = current_mesh()
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
 
     def one(path, x):

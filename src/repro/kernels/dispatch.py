@@ -35,7 +35,10 @@ Selection can be overridden without touching call sites, in precedence order:
 3. The call-site ``impl=`` argument (default ``"auto"``).
 
 Introspection for benchmarks and tests: :func:`available_impls`,
-:func:`KernelRegistry.kernels`, :func:`KernelRegistry.get`.
+:func:`KernelRegistry.kernels`, :func:`KernelRegistry.get`, and
+:func:`KernelRegistry.record`, which collects the variants actually chosen
+inside a ``with`` block (how a run on the chip proves that no kernel fell
+back from Pallas).
 """
 from __future__ import annotations
 
@@ -52,13 +55,12 @@ Predicate = Callable[[Ctx], bool]
 
 
 def on_tpu() -> bool:
-    """True when the default jax backend is a TPU (shared by all kernels)."""
+    """True when the default jax backend is a TPU (shared by all kernels).
+    A backend that fails to initialise raises here rather than reading as
+    "not a TPU": that would quietly run the Pallas interpreter on the chip."""
     import jax
 
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @dataclass(frozen=True)
@@ -144,6 +146,21 @@ class KernelRegistry:
         finally:
             stack.pop()
 
+    @contextmanager
+    def record(self):
+        """Yield a ``{kernel: {impl, ...}}`` dict that collects every
+        resolution made inside the ``with`` block (trace time for jitted
+        code). Thread-local, like :meth:`force_impl`."""
+        records = getattr(self._local, "records", None)
+        if records is None:
+            records = self._local.records = []
+        seen: dict[str, set[str]] = {}
+        records.append(seen)
+        try:
+            yield seen
+        finally:
+            records.remove(seen)
+
     def _forced(self, kernel: str) -> Optional[tuple[str, bool]]:
         """(impl, is_global) from the innermost applicable force_impl."""
         for scope, impl in reversed(getattr(self._local, "stack", []) or []):
@@ -169,6 +186,12 @@ class KernelRegistry:
     def resolve(self, kernel: str, impl: str = "auto",
                 ctx: Optional[Ctx] = None) -> Variant:
         """Pick the variant that will run for this call context."""
+        v = self._select(kernel, impl, ctx)
+        for seen in getattr(self._local, "records", None) or ():
+            seen.setdefault(kernel, set()).add(v.name)
+        return v
+
+    def _select(self, kernel: str, impl: str, ctx: Optional[Ctx]) -> Variant:
         table = self._table(kernel)
         full_ctx = dict(ctx or {})
         full_ctx.setdefault("on_tpu", on_tpu())

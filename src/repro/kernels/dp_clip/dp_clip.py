@@ -39,7 +39,7 @@ def _sumsq_kernel(g_ref, o_ref, acc, *, n_d: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_d", "interpret"))
-def per_example_sumsq(g, block_b: int = 8, block_d: int = 512, interpret: bool = True):
+def per_example_sumsq(g, block_b: int = 8, block_d: int = 512, interpret: bool = False):
     B, D = g.shape
     block_b = min(block_b, B)
     block_d = min(block_d, D)
@@ -75,7 +75,7 @@ def _clip_accum_kernel(g_ref, s_ref, o_ref, acc, *, n_b: int):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_d", "interpret"))
 def clip_accumulate(g, scale, block_b: int = 8, block_d: int = 512,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """sum_b g[b] * scale[b] -> (D,) fp32."""
     B, D = g.shape
     block_b = min(block_b, B)

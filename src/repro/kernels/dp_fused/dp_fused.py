@@ -65,16 +65,16 @@ def _clip_sum_kernel(cb_ref, g_ref, sum_ref, norm_ref, ss_acc, d_acc, *,
 
         @pl.when(d == 0)
         def _init():
-            pl.store(ss_acc, rows, part)
+            ss_acc[rows] = part
 
         @pl.when(d != 0)
         def _accum():
-            pl.store(ss_acc, rows, pl.load(ss_acc, rows) + part)
+            ss_acc[rows] = ss_acc[rows] + part
 
     @pl.when(p == 1)
     def _phase_accumulate():
         g = g_ref[...].astype(jnp.float32)
-        ss = pl.load(ss_acc, rows)                     # (block_b, 1)
+        ss = ss_acc[rows]                              # (block_b, 1)
         norms = jnp.sqrt(jnp.maximum(ss, 1e-30))
         scale = jnp.minimum(1.0, cb_ref[0] / norms)
         part = jnp.sum(g * scale, axis=0, keepdims=True)  # (1, block_d)
@@ -97,7 +97,7 @@ def _clip_sum_kernel(cb_ref, g_ref, sum_ref, norm_ref, ss_acc, d_acc, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def clip_sum_pallas(g, clip_bound, block_d: int = 512, interpret: bool = True):
+def clip_sum_pallas(g, clip_bound, block_d: int = 512, interpret: bool = False):
     """g: (B, P) packed per-example grads; P % block_d == 0 (flatbuf pads
     totals to ALIGN=1024). Returns (clipped_sum (P,), pre-clip norms (B,))."""
     B, P = g.shape
@@ -171,7 +171,7 @@ def _clip_mask_kernel(ints_ref, flts_ref, g_ref, o_ref, *, block_d: int,
 def clip_mask_pallas(g, scale, key_r, key_xi, prev_key, silo, n_silos: int,
                      sigma_c, b_scale, lam_gate, use_pairwise: bool = True,
                      use_prev: bool = True, block_d: int = 1024,
-                     interpret: bool = True, *, nxt=None, noise_scale=None,
+                     interpret: bool = False, *, nxt=None, noise_scale=None,
                      prev_noise_scale=None):
     """g: packed (P,) buffer; key_*: (2,) uint32; silo traceable int32.
     Returns fp32 ``g*scale + b*(r_i - r_nxt) + s*xi_t - lam_gate*s_prev*xi_prev``.
@@ -254,7 +254,7 @@ def _noise_batch_kernel(ints_ref, flts_ref, scales_ref, lams_ref, g_ref,
                                              "interpret"))
 def noise_batch_pallas(g_sum, key_xi, prev_key, noise_scales, lam_gates,
                        prev_noise_scale, use_prev: bool = True,
-                       block_d: int = 1024, interpret: bool = True):
+                       block_d: int = 1024, interpret: bool = False):
     """g_sum: packed (P,) aggregate; key_xi/prev_key: (2,) uint32;
     noise_scales/lam_gates: per-silo (n,) fp32 (participation gates folded
     in by the caller). Returns fp32

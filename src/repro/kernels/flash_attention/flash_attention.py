@@ -64,7 +64,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, causal: bool = True, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool = False):
     """q: (B,Sq,Hq,D); k,v: (B,Sk,Hkv,D). Returns (B,Sq,Hq,D)."""
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]

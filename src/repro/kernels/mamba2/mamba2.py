@@ -64,7 +64,7 @@ def _ssd_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, h0_ref, y_ref, h_out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_pallas(xh, dt, la, Bc, Cc, h0, chunk: int = 64, interpret: bool = True):
+def ssd_pallas(xh, dt, la, Bc, Cc, h0, chunk: int = 64, interpret: bool = False):
     """xh: (B,S,nh,P) fp32; dt, la: (B,S,nh); Bc, Cc: (B,S,N);
     h0: (B,nh,P,N). Returns (y, h_final)."""
     B, S, nh, P = xh.shape

@@ -51,11 +51,11 @@ def _jnp(q, k_pages, v_pages, tables, q_start):
 def paged_attention(q, k_pages, v_pages, tables, q_start, impl: str = "auto"):
     """Attention for C new tokens per slot against the slot's paged KV.
 
-    q: (B, C, Hq, D); k_pages/v_pages: (N, P, Hkv, D); tables: (B, nP) i32;
+    q: (B, C, Hq, D); k_pages/v_pages: (N, Hkv, P, D); tables: (B, nP) i32;
     q_start: (B,) i32 tokens already cached (q row c reads positions
     <= q_start + c). Returns fp32 (B, C, Hq, D)."""
     return REGISTRY.dispatch(KERNEL, impl,
-                             {"C": q.shape[1], "P": k_pages.shape[1]},
+                             {"C": q.shape[1], "P": k_pages.shape[2]},
                              q, k_pages, v_pages, tables, q_start)
 
 
@@ -74,7 +74,7 @@ def _reset_jnp(k_pages, v_pages, row):
 
 def paged_reset(k_pages, v_pages, row, impl: str = "auto"):
     """Zero the pages in block-table row ``row`` (shape (nP,) i32) across the
-    stacked (L, N, P, H, D) pools; returns the new (k_pages, v_pages).
+    stacked (L, N, H, P, D) pools; returns the new (k_pages, v_pages).
 
     Treat the input pools as CONSUMED: the Pallas path donates them for the
     in-place alias, so callers must rebind (``pool = paged_reset(*pool, row)``)
@@ -99,7 +99,7 @@ def _rollback_jnp(k_pages, v_pages, row, bounds):
 
 def paged_rollback(k_pages, v_pages, row, start, end, impl: str = "auto"):
     """Zero logical token positions ``[start, end)`` of block-table row
-    ``row`` across the stacked (L, N, P, H, D) pools; returns the new
+    ``row`` across the stacked (L, N, H, P, D) pools; returns the new
     (k_pages, v_pages). Page ``j`` of the row covers positions
     ``j*P .. j*P+P-1``; ``start``/``end`` are host ints.
 
@@ -117,7 +117,7 @@ def paged_rollback(k_pages, v_pages, row, start, end, impl: str = "auto"):
     start, end = int(start), int(end)
     if end <= start:
         return k_pages, v_pages
-    P = k_pages.shape[2]
+    P = k_pages.shape[3]
     sp, ep = start // P, -(-end // P)
     sub = row[sp:ep]
     bounds = jnp.asarray([start - sp * P, end - sp * P], jnp.int32)

@@ -24,7 +24,9 @@ by 1 ulp).
 
 Layouts:
   q                (B, C, Hq, D)   — C = chunk of new tokens per slot
-  k_pages/v_pages  (N, P, Hkv, D)  — one layer's pool: N pages of P tokens
+  k_pages/v_pages  (N, Hkv, P, D)  — one layer's pool: N pages of P tokens,
+                                     head-major so a kernel block is one
+                                     head's (P, D) page (TPU (8, 128) tiling)
   tables           (B, nP) int32   — per-slot physical page ids
   q_start          (B,)    int32   — tokens already in the slot's cache
                                      (q row c sits at position q_start + c)
@@ -108,8 +110,8 @@ def _paged_kernel(tables_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)      # (gc, D) — row-padded fold
-    k = k_ref[0, :, 0].astype(jnp.float32)   # (P, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)      # (P, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     mask = _mask(qstart_ref[b], j, page_size, chunk, gc)
     m, l, acc = _page_step(q, k, v, m_scr[...], l_scr[...], acc_scr[...],
                            mask, sm_scale)
@@ -133,11 +135,11 @@ def paged_attention_pallas(q, k_pages, v_pages, tables, q_start,
     from the scalar-prefetched table row, so the kernel's reachable memory
     is exactly the slot's own pages."""
     B, C, Hq, D = q.shape
-    _, P, Hkv, _ = k_pages.shape
+    _, Hkv, P, _ = k_pages.shape
     nP = tables.shape[1]
     qt, GC, GCp = _fold_padded(q, B, C, Hq, Hkv, D)
     q_spec = pl.BlockSpec((1, 1, GCp, D), lambda b, h, j, t, qs: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, P, 1, D), lambda b, h, j, t, qs: (t[b, j], 0, h, 0))
+    kv_spec = pl.BlockSpec((1, 1, P, D), lambda b, h, j, t, qs: (t[b, j], h, 0, 0))
     o_spec = pl.BlockSpec((1, 1, GCp, D), lambda b, h, j, t, qs: (b, h, 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_kernel, page_size=P, n_pages=nP, chunk=C,
@@ -171,7 +173,7 @@ def _rollback_kernel(row_ref, bounds_ref, k_ref, v_ref, ko_ref, vo_ref, *,
     j = pl.program_id(1)
     start, end = bounds_ref[0], bounds_ref[1]
     P = page_size
-    pos = j * P + jax.lax.broadcasted_iota(jnp.int32, (P, 1, 1), 0)
+    pos = j * P + jax.lax.broadcasted_iota(jnp.int32, (1, P, 1), 1)
     dead = (pos >= start) & (pos < end)
     ko_ref[0, 0] = jnp.where(dead, 0.0, k_ref[0, 0].astype(jnp.float32)) \
         .astype(ko_ref.dtype)
@@ -183,7 +185,7 @@ def _rollback_kernel(row_ref, bounds_ref, k_ref, v_ref, ko_ref, vo_ref, *,
                    donate_argnums=(0, 1))
 def paged_rollback_pallas(k_pages, v_pages, row, bounds, interpret=False):
     """Zero the K/V of logical token positions ``[bounds[0], bounds[1])`` in
-    block-table row ``row`` across every layer of the stacked (L, N, P, H, D)
+    block-table row ``row`` across every layer of the stacked (L, N, H, P, D)
     pools, in place (the speculative-decoding rejected-tail eraser).
 
     ``row`` must be duplicate-free (unlike ``paged_reset``): a duplicate
@@ -196,7 +198,7 @@ def paged_rollback_pallas(k_pages, v_pages, row, bounds, interpret=False):
     spec = pl.BlockSpec((1, 1) + k_pages.shape[2:],
                         lambda l, j, row, bounds: (l, row[j], 0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_rollback_kernel, page_size=k_pages.shape[2]),
+        functools.partial(_rollback_kernel, page_size=k_pages.shape[3]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(L, nP),
             in_specs=[spec, spec], out_specs=[spec, spec],
@@ -212,7 +214,7 @@ def paged_rollback_pallas(k_pages, v_pages, row, bounds, interpret=False):
                    donate_argnums=(0, 1))
 def paged_reset_pallas(k_pages, v_pages, row, interpret=False):
     """Zero the pages named in block-table row ``row`` across every layer of
-    the stacked (L, N, P, H, D) pools, in place (``input_output_aliases``;
+    the stacked (L, N, H, P, D) pools, in place (``input_output_aliases``;
     the jit donates the pools so no copy materializes). A row may repeat a
     page id — zeroing is idempotent, which lets callers pad short rows with
     their own first page instead of a reserved sentinel."""

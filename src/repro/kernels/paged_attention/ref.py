@@ -24,7 +24,7 @@ def paged_attention_oracle(q, k_pages, v_pages, tables, q_start):
     inside one jit — same helpers, same op sequence, bit-equal output.
     Test-sized pools only (compile time is cubic in the unroll)."""
     B, C, Hq, D = q.shape
-    _, P, Hkv, _ = k_pages.shape
+    _, Hkv, P, _ = k_pages.shape
     nP = tables.shape[1]
     qt, GC, GCp = _fold_padded(q, B, C, Hq, Hkv, D)
     sm_scale = 1.0 / D ** 0.5
@@ -39,8 +39,8 @@ def paged_attention_oracle(q, k_pages, v_pages, tables, q_start):
             acc = jnp.zeros((GCp, D), jnp.float32)
             for j in range(nP):
                 page = tables[b, j]
-                k = k_pages[page, :, h].astype(jnp.float32)
-                v = v_pages[page, :, h].astype(jnp.float32)
+                k = k_pages[page, h].astype(jnp.float32)
+                v = v_pages[page, h].astype(jnp.float32)
                 mask = _mask(q_start[b], j, P, C, GCp)
                 m, l, acc = _page_step(qf, k, v, m, l, acc, mask, sm_scale)
             heads.append((acc / jnp.maximum(l, 1e-30))[:GC])
@@ -54,12 +54,16 @@ def paged_attention_gather(q, k_pages, v_pages, tables, q_start):
     (B, nP*P) view, then one masked GQA softmax. O(nP*P) score memory per
     query — fine for serving-sized pools, and XLA fuses the gather."""
     B, C, Hq, D = q.shape
-    _, P, Hkv, _ = k_pages.shape
+    _, Hkv, P, _ = k_pages.shape
     nP = tables.shape[1]
     G = Hq // Hkv
     S = nP * P
-    k = k_pages[tables].reshape(B, S, Hkv, D)  # (B, nP, P, Hkv, D) -> flat
-    v = v_pages[tables].reshape(B, S, Hkv, D)
+
+    def gather(pages):  # (B, nP, Hkv, P, D) -> (B, nP*P, Hkv, D)
+        return pages[tables].transpose(0, 1, 3, 2, 4).reshape(B, S, Hkv, D)
+
+    k = gather(k_pages)
+    v = gather(v_pages)
     qg = q.reshape(B, C, Hkv, G, D).astype(jnp.float32)
     scores = jnp.einsum("bchgd,bkhd->bhgck", qg,
                         k.astype(jnp.float32)) * (1.0 / D ** 0.5)
@@ -79,7 +83,7 @@ def paged_attention_gather(q, k_pages, v_pages, tables, q_start):
 
 @jax.jit
 def paged_reset_ref(k_pages, v_pages, row):
-    """Zero block-table row ``row``'s pages in the stacked (L, N, P, H, D)
+    """Zero block-table row ``row``'s pages in the stacked (L, N, H, P, D)
     pools. Duplicate page ids in the row are fine (idempotent zero)."""
     return (k_pages.at[:, row].set(0.0), v_pages.at[:, row].set(0.0))
 
@@ -87,7 +91,7 @@ def paged_reset_ref(k_pages, v_pages, row):
 @jax.jit
 def paged_rollback_ref(k_pages, v_pages, row, bounds):
     """Zero logical token positions ``[bounds[0], bounds[1])`` of block-table
-    row ``row`` in the stacked (L, N, P, H, D) pools.
+    row ``row`` in the stacked (L, N, H, P, D) pools.
 
     Implemented as a scatter-*multiply* by a 0/1 keep mask rather than a
     gather/where/set round-trip: a short row pads with duplicate page ids,
@@ -96,9 +100,9 @@ def paged_rollback_ref(k_pages, v_pages, row, bounds):
     zeroed lanes. Multiplies compose — the pad visit is a multiply-by-one
     no-op regardless of ordering."""
     nP = row.shape[0]
-    P = k_pages.shape[2]
+    P = k_pages.shape[3]
     pos = jnp.arange(nP)[:, None] * P + jnp.arange(P)[None, :]
     keep = (~((pos >= bounds[0]) & (pos < bounds[1]))).astype(k_pages.dtype)
-    keep = keep[None, :, :, None, None]
+    keep = keep[None, :, None, :, None]
     return (k_pages.at[:, row].multiply(keep),
             v_pages.at[:, row].multiply(keep))

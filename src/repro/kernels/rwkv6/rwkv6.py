@@ -67,7 +67,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv_pallas(r, k, v, w, u, state0, chunk: int = 32, interpret: bool = True):
+def wkv_pallas(r, k, v, w, u, state0, chunk: int = 32, interpret: bool = False):
     """r,k,v,w: (B,S,H,N) fp32; u: (H,N); state0: (B,H,N,N) fp32."""
     B, S, H, N = r.shape
     assert S % chunk == 0

@@ -4,6 +4,7 @@ and in interpret mode, bit-identical between the kernel and the oracle).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 _ROT_A = (13, 15, 26, 6)
@@ -72,8 +73,13 @@ def threefry2x32(k0, k1, x0, x1):
 
 
 def uniform01(bits):
-    """uint32 -> float32 uniform in (0, 1]."""
-    return (bits.astype(jnp.float32) + 1.0) * (1.0 / 4294967296.0)
+    """uint32 -> float32 uniform in (0, 1], by bit manipulation only (the
+    TPU's Mosaic compiler has no uint32 -> float32 convert): the top 23 bits
+    become the mantissa of a float in [1, 2), and ``2 - x`` is exact and lies
+    in (0, 1], which the Box-Muller ``log`` needs."""
+    x = jax.lax.bitcast_convert_type(
+        (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000), jnp.float32)
+    return 2.0 - x
 
 
 def normal_pair(k0, k1, c0, c1):

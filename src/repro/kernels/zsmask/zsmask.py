@@ -49,7 +49,7 @@ def _zsmask_kernel(ints_ref, flts_ref, g_ref, o_ref, *, block_d: int):
 
 @functools.partial(jax.jit, static_argnames=("n_silos", "block_d", "interpret"))
 def zsmask_pallas(g, key_r, key_xi, silo, n_silos: int, sigma_c, b_scale,
-                  block_d: int = 1024, interpret: bool = True):
+                  block_d: int = 1024, interpret: bool = False):
     """g: flat (D,). key_*: (2,) uint32. silo: int32 scalar (traceable)."""
     D = g.shape[0]
     block_d = min(block_d, D)

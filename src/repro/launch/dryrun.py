@@ -29,7 +29,8 @@ from repro.configs import (SHAPES, get_config, runnable_cells, param_count,
 from repro.configs.base import (MeshConfig, ModelConfig, OptimizerConfig,
                                 PrivacyConfig, RunConfig, ShapeConfig)
 from repro.distributed import steps as steps_mod
-from repro.distributed.sharding_rules import params_pspecs, spec_for
+from repro.distributed.sharding_rules import (current_mesh, params_pspecs,
+                                              spec_for)
 from repro.launch import specs as specs_mod
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                                make_production_mesh, mesh_config)
@@ -54,9 +55,8 @@ def cache_pspecs(cache, mesh_cfg: MeshConfig):
             # when kv_heads < TP, put the model axis on the cache's seq dim
             # instead (§Perf iteration 4: mistral decode cache was 284GB/dev
             # with only batch-sharding — kv=8 can't fill model=16)
-            from repro import compat as _compat
-            mesh = _compat.get_abstract_mesh()
-            tp = mesh.shape.get("model", 1) if mesh and mesh.axis_names else 1
+            mesh = current_mesh()
+            tp = mesh.shape.get("model", 1) if mesh is not None else 1
             seq_name = "seq_tp" if (x.shape[3] % max(tp, 1) != 0) else None
             if x.shape[1] % silo_n == 0 and x.shape[1] > 1:
                 return spec_for((None, "batch", seq_name, "kv_heads", None), x.shape)

@@ -5,7 +5,9 @@ never touches jax device state.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
+
 from repro.configs.base import MeshConfig
 
 
@@ -14,7 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     ``pod`` outermost so cross-pod collectives map to the DCI dimension."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -24,8 +26,8 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 
 def make_mesh_from_config(cfg: MeshConfig):
-    return make_mesh(cfg.shape, cfg.axes,
-                     axis_types=(AxisType.Auto,) * len(cfg.axes))
+    return jax.make_mesh(cfg.shape, cfg.axes,
+                         axis_types=(AxisType.Auto,) * len(cfg.axes))
 
 
 # TPU v5e hardware constants (roofline targets; this container is CPU-only)

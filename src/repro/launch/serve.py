@@ -23,6 +23,7 @@ import os
 import numpy as np
 
 from repro.api import Session
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _rolling_p99(latencies, window: int = 64):
@@ -107,6 +108,7 @@ def main():
     ap.add_argument("--out", default="BENCH_serve.json",
                     help="soak mode: benchmark file to merge the row into")
     args = ap.parse_args()
+    enable_compile_cache()
 
     sess = Session.from_config(args.arch, full=args.full)
     if not sess.cfg.causal:

@@ -12,6 +12,7 @@ import json
 
 from repro.api import Session
 from repro.configs.base import OptimizerConfig, PrivacyConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run_wire(args):
@@ -148,6 +149,7 @@ def main():
                     help="wire tier: crash-consistent round journal; if the "
                          "file exists the run RESUMES from it")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.wire_rounds is not None:
         return run_wire(args)

@@ -147,8 +147,10 @@ def init_paged_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     """Shared page pool for ``n_entries`` layers: requests own disjoint sets
     of pages via block tables instead of contiguous per-request caches, so a
     finished request's pages recycle into any slot (after the in-kernel
-    zeroing — see kernels/paged_attention)."""
-    shape = (n_entries, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+    zeroing — see kernels/paged_attention). Pages are head-major,
+    (N, Hkv, P, D) per layer, so the kernel reads one head's page as a
+    (P, D) tile."""
+    shape = (n_entries, n_pages, cfg.n_kv_heads, page_size, cfg.d_head)
     return {"k_pages": jnp.zeros(shape, dtype), "v_pages": jnp.zeros(shape, dtype)}
 
 
@@ -156,7 +158,7 @@ def paged_kv_write(k_pages, v_pages, k, v, tables, q_start, n_valid):
     """Scatter a (B, C, Hkv, Dh) chunk of fresh K/V into the slots' own
     pages. Rows past ``n_valid`` scatter to page id N (one past the pool) and
     are dropped, so inactive slots and prompt padding write nothing."""
-    N, P = k_pages.shape[0], k_pages.shape[1]
+    N, P = k_pages.shape[0], k_pages.shape[2]
     B, C = k.shape[0], k.shape[1]
     pos = q_start[:, None] + jnp.arange(C)[None, :]            # (B, C)
     valid = jnp.arange(C)[None, :] < n_valid[:, None]
@@ -164,8 +166,10 @@ def paged_kv_write(k_pages, v_pages, k, v, tables, q_start, n_valid):
                                axis=1)
     page = jnp.where(valid, page, N)                           # OOB -> dropped
     off = pos % P
-    k_pages = k_pages.at[page, off].set(k.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[page, off].set(v.astype(v_pages.dtype), mode="drop")
+    # the (B, C) index arrays around the head slice put (B, C) first, so the
+    # indexed view is (B, C, Hkv, Dh): the chunk's own layout
+    k_pages = k_pages.at[page, :, off].set(k.astype(k_pages.dtype), mode="drop")
+    v_pages = v_pages.at[page, :, off].set(v.astype(v_pages.dtype), mode="drop")
     return k_pages, v_pages
 
 

@@ -13,8 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
+from repro.distributed import sharding_rules
 from repro.distributed.sharding_rules import constrain
 from repro.models.layers import dense_init
 
@@ -63,10 +63,10 @@ def _dispatch_groups() -> int:
     under pjit (EXPERIMENTS.md §Perf iteration 1: the global-cumsum dispatch
     all-reduced full (E*C, D) buffers, 2e12 B/chip on qwen3-moe; a nested
     shard_map formulation crashed XLA, so groups-by-construction it is)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = sharding_rules.current_mesh()
+    if mesh is None:
         return 1
-    auto = compat.auto_axis_names(mesh)
+    auto = sharding_rules.auto_axis_names(mesh)
     g = 1
     for name in mesh.axis_names:
         if name in ("pod", "data") and name in auto:

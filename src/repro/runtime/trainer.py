@@ -285,8 +285,7 @@ class Trainer:
 
         with ExitStack() as stack:
             if self._owns_mesh:
-                from repro import compat
-                stack.enter_context(compat.set_mesh(self.mesh))
+                stack.enter_context(jax.set_mesh(self.mesh))
             return self._fit(state, root_key)
 
     def _fit(self, state, root_key) -> tuple:

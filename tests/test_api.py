@@ -83,3 +83,24 @@ def test_trainer_metrics_log_survives_restart(tmp_path):
     assert res2.step == 4
     steps_seen = [m["step"] for m in res2.metrics]
     assert steps_seen == [0, 1, 2, 3]  # old history + resumed steps, no gap
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch):
+    """The entry points' cache helper: JAX_COMPILATION_CACHE_DIR, when set,
+    is left to JAX; otherwise the cache goes to one fixed in-checkout path."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == jax.config.jax_compilation_cache_dir
+        assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -119,6 +119,19 @@ def test_global_override_with_foreign_impl_name_is_ignored(monkeypatch):
         reg.resolve("k", "auto", ctx)
 
 
+def test_record_collects_resolved_variants():
+    """record() sees what actually ran, fallbacks included, and only inside
+    its block; nested records both see the inner resolutions."""
+    reg = _toy_registry()
+    with reg.record() as outer:
+        reg.resolve("k", "auto", {"n": 8, "on_tpu": True})
+        with reg.record() as inner:
+            reg.resolve("k", "fast", {"n": 7, "on_tpu": True})  # incapable
+    reg.resolve("k", "mid", {"n": 8, "on_tpu": False})
+    assert outer == {"k": {"fast", "ref"}}
+    assert inner == {"k": {"ref"}}
+
+
 def test_dispatch_calls_selected_fn():
     reg = _toy_registry()
     assert reg.dispatch("k", "auto", {"n": 8, "on_tpu": False}, 42) == ("ref", 42)

@@ -316,7 +316,7 @@ def test_drop_and_rejoin_carries_correction_state():
 
 BARRIER_PARITY_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.configs.base import (MeshConfig, OptimizerConfig, PrivacyConfig,
                                 RunConfig, SHAPES)
 from repro.configs.paper_models import MNIST_MLP3
@@ -341,9 +341,9 @@ params = model.init(jax.random.PRNGKey(0))
 keys = barrier_mod.step_keys(jax.random.PRNGKey(9), jnp.zeros((), jnp.int32))
 ns = init_state(jax.random.PRNGKey(0), n_silos=N)
 
-mesh = make_mesh((N,), ("data",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((N,), ("data",), axis_types=(AxisType.Auto,))
 for active_np in (np.ones(N, bool), np.array([True, False, True, True])):
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         barrier, loss, norms, new_ns, bound = jax.jit(
             lambda p, b, a: steps_mod._barrier_grads(
                 model, priv, mesh_cfg, p, b, keys, ns, jnp.float32(1.0),

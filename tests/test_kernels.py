@@ -142,6 +142,16 @@ def test_threefry_reference_vector():
     assert (int(x0[0]), int(x1[0])) == (0x6B200159, 0x99BA4EFE)
 
 
+def test_uniform01_range_and_resolution():
+    """The bitcast construction maps the top 23 bits onto a 2^-23 grid in
+    (0, 1]: never 0 (the Box-Muller log), 1.0 at the bottom of the range."""
+    from repro.kernels.zsmask.threefry import uniform01
+    bits = jnp.asarray([0, 0x1FF, 0x200, 0x80000000, 0xFFFFFFFF], jnp.uint32)
+    np.testing.assert_array_equal(
+        np.asarray(uniform01(bits)),
+        np.asarray([1.0, 1.0, 1.0 - 2.0 ** -23, 0.5, 2.0 ** -23], np.float32))
+
+
 @settings(deadline=None, max_examples=10)
 @given(st.integers(0, 2**31 - 1), st.integers(2, 9))
 def test_zsmask_gaussianity(seed, n):

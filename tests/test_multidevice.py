@@ -23,7 +23,7 @@ def run_script(body: str):
 
 BARRIER_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config
 from repro.configs.base import RunConfig, MeshConfig, PrivacyConfig, OptimizerConfig, SHAPES
 from repro.models.registry import build_model
@@ -31,7 +31,7 @@ from repro.distributed import steps as steps_mod
 from repro.core import barrier as barrier_mod, clipping
 from repro.core.noise_correction import init_state
 
-mesh = make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,)*3)
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,)*3)
 cfg = get_smoke_config("qwen2.5-3b")
 model = build_model(cfg, compute_dtype=jnp.float32)
 mesh_cfg = MeshConfig((2,2,2), ("pod","data","model"))
@@ -43,7 +43,7 @@ key = jax.random.PRNGKey(0)
 B, S = 8, 32
 batch = {"tokens": jax.random.randint(key, (B,S), 0, cfg.vocab_size),
          "labels": jax.random.randint(key, (B,S), 0, cfg.vocab_size)}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state = steps_mod.init_train_state(model, rc, key)
     ts = jax.jit(steps_mod.build_train_step(model, rc, abstract_mesh=mesh))
     new_state, metrics = ts(state, batch, jax.random.PRNGKey(42))
@@ -61,7 +61,7 @@ noise = barrier_mod.aggregate_noise_from_streams(state.params, keys, n, 0.5*1.0)
 expect = jax.tree.map(lambda a,b: a + b, manual, noise)
 
 # recover the aggregate (lr=0 sgd keeps params; recompute noisy path)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     noisy, loss, norms, _, _ = jax.jit(lambda p, b: steps_mod._barrier_grads(
         model, priv, mesh_cfg, p, b, keys, state.noise_state,
         jnp.float32(1.0), keys.key_clip, mesh))(state.params, batch)
@@ -76,7 +76,7 @@ print("OK")
 DRYRUN_SCRIPT = r"""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.configs import get_smoke_config
 from repro.configs.base import RunConfig, MeshConfig, PrivacyConfig, OptimizerConfig, SHAPES
 from repro.models.registry import build_model
@@ -84,7 +84,7 @@ from repro.distributed import steps as steps_mod
 from repro.distributed.sharding_rules import named_shardings
 from repro.analysis.hlo_cost import analyze
 
-mesh = make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,)*3)
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"), axis_types=(AxisType.Auto,)*3)
 mesh_cfg = MeshConfig((2,2,2), ("pod","data","model"))
 for arch in ("qwen2.5-3b", "phi3.5-moe-42b-a6.6b", "rwkv6-7b"):
     cfg = get_smoke_config(arch)
@@ -93,7 +93,7 @@ for arch in ("qwen2.5-3b", "phi3.5-moe-42b-a6.6b", "rwkv6-7b"):
                          silo_mode="scan", n_silos=2)
     rc = RunConfig(model=cfg, shape=SHAPES["train_4k"], mesh=mesh_cfg, privacy=priv)
     step = steps_mod.build_train_step(model, rc, abstract_mesh=mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state_sds = jax.eval_shape(lambda: steps_mod.init_train_state(model, rc, jax.random.PRNGKey(0)))
         st_specs = steps_mod.state_pspecs(state_sds)
         batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32),
@@ -114,26 +114,6 @@ print("OK")
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    reason="pre-existing XLA SPMD partitioner CHECK-crash (sharding "
-           "propagation across the shard_map boundary on the mixed "
-           "(pod,data,model) mesh tries an invalid manual<->auto reshard; "
-           "SIGABRT in the subprocess). Tracked since PR 1; the barrier "
-           "tier's numerics are covered on a pure silo mesh by "
-           "tests/test_dp_pipeline.py::test_barrier_tier_parity_on_mesh. "
-           "Retried in PR 4 — not fixable from Python on jax 0.4.37: "
-           "(1) explicit in/out_shardings on the enclosing jit (state_pspecs"
-           "/batch_pspec named shardings) hit the identical CHECK at "
-           "spmd_partitioner.cc:517 — the bad reshard is on an internal "
-           "rank-3 stacked-param tensor, not a jit boundary value; "
-           "(2) jax_use_shardy_partitioner=True fails earlier (UNIMPLEMENTED:"
-           " PartitionId under SPMD partitioning); (3) with_sharding_"
-           "constraint(model-axis specs) inside the shard_map body is "
-           "emitted without the manual subgroup annotation on 0.4.37 and "
-           "trips 'Incompatible manual sharding' (RET_CHECK spmd_partitioner"
-           ".cc:2468). Needs a jax/XLA upgrade (modern shard_map composes "
-           "manual axes into in-body constraints).",
-    strict=False)
 def test_barrier_path_exact_on_mesh():
     out = run_script(BARRIER_SCRIPT)
     assert "OK" in out

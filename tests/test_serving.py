@@ -66,8 +66,8 @@ def test_elastic_restore_with_shardings(tmp_path):
 def _paged_inputs(B, C, Hq, Hkv, D, N, P, nP, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, C, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (N, P, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, P, Hkv, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (N, Hkv, P, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (N, Hkv, P, D), jnp.float32)
     tables = jnp.asarray(np.stack(
         [np.random.RandomState(b).permutation(N)[:nP] for b in range(B)]
     ).astype(np.int32))
@@ -112,9 +112,9 @@ def test_paged_reset_parity_and_isolation():
     from repro.kernels.paged_attention import ref as pref
     from repro.kernels.paged_attention.paged_attention import \
         paged_reset_pallas
-    L, N, P, H, D = 2, 6, 4, 2, 8
-    base = jnp.arange(L * N * P * H * D,
-                      dtype=jnp.float32).reshape(L, N, P, H, D) + 1
+    L, N, H, P, D = 2, 6, 2, 4, 8
+    base = jnp.arange(L * N * H * P * D,
+                      dtype=jnp.float32).reshape(L, N, H, P, D) + 1
     row = jnp.array([3, 1, 3], jnp.int32)  # duplicate on purpose
     kj, vj = pref.paged_reset_ref(base, base * 2, row)
     # fresh arrays for the pallas call: its jit donates the inputs
@@ -375,9 +375,9 @@ def test_paged_rollback_parity_and_isolation():
     straddling a page boundary; positions outside [start, end) and pages
     outside the slot's row are bit-untouched."""
     from repro.kernels.paged_attention import ops as paged_ops
-    L, N, P, H, D = 2, 6, 4, 2, 8
-    base = jnp.arange(L * N * P * H * D,
-                      dtype=jnp.float32).reshape(L, N, P, H, D) + 1
+    L, N, H, P, D = 2, 6, 2, 4, 8
+    base = jnp.arange(L * N * H * P * D,
+                      dtype=jnp.float32).reshape(L, N, H, P, D) + 1
     row = np.asarray([3, 1, 5], np.int32)  # the slot's pages: positions 0..11
     start, end = 5, 10                     # straddles pages 1 and 5
     kj, vj = paged_ops.paged_rollback(base, base * 2, row, start, end,
@@ -388,10 +388,10 @@ def test_paged_rollback_parity_and_isolation():
     np.testing.assert_array_equal(np.asarray(kj), np.asarray(kp))
     np.testing.assert_array_equal(np.asarray(vj), np.asarray(vp))
     out, ref = np.asarray(kp), np.asarray(base)
-    assert (out[:, 1, 1:] == 0).all()   # positions 5..7
-    assert (out[:, 5, :2] == 0).all()   # positions 8..9
-    np.testing.assert_array_equal(out[:, 1, :1], ref[:, 1, :1])  # position 4
-    np.testing.assert_array_equal(out[:, 5, 2:], ref[:, 5, 2:])  # 10..11
+    assert (out[:, 1, :, 1:] == 0).all()   # positions 5..7
+    assert (out[:, 5, :, :2] == 0).all()   # positions 8..9
+    np.testing.assert_array_equal(out[:, 1, :, :1], ref[:, 1, :, :1])  # 4
+    np.testing.assert_array_equal(out[:, 5, :, 2:], ref[:, 5, :, 2:])  # 10..11
     np.testing.assert_array_equal(out[:, 3], ref[:, 3])  # page before start
     keep = [0, 2, 4]                    # pages not in the row at all
     np.testing.assert_array_equal(out[:, keep], ref[:, keep])

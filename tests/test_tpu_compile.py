@@ -1,0 +1,114 @@
+"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+
+Interpret mode on the CPU cannot see what the TPU compiler (Mosaic) refuses:
+block shapes off the (8, 128) tiling, casts it has no lowering for, too much
+VMEM. Each test lowers one kernel for a described (not attached) v5e chip at
+qwen2.5-3b widths (d_head 128, 16 query / 2 kv heads) and at the packed
+gradient size of the 2-layer, 37,984-vocabulary-row cut that
+``chip_smoke.py`` trains, and checks that the compiled program holds the
+kernel as a ``tpu_custom_call``. Nothing runs; a compile takes about a
+second.
+
+The topology is described inside a fixture and never at import: only one
+process may load the TPU library at a time.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.qwen25_3b import CONFIG
+from repro.core import flatbuf
+from repro.models.registry import build_model
+
+N_SILOS = 2
+SLOTS, PAGE, PAGES_PER_SLOT = 8, 16, 36  # 8 requests, 576-token slots
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def packed_size():
+    cfg = dataclasses.replace(CONFIG, n_layers=2, vocab_size=37984)
+    params = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
+    return flatbuf.layout_of(params).total
+
+
+def _cases(P):
+    """name -> (kernel call, argument shapes) at the chip_smoke widths."""
+    from repro.kernels.dp_clip.dp_clip import clip_accumulate, \
+        per_example_sumsq
+    from repro.kernels.dp_fused.dp_fused import (clip_mask_pallas,
+                                                 clip_sum_pallas,
+                                                 noise_batch_pallas)
+    from repro.kernels.paged_attention.paged_attention import (
+        paged_attention_pallas, paged_reset_pallas, paged_rollback_pallas)
+    from repro.kernels.zsmask.zsmask import zsmask_pallas
+
+    f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32
+    key, sc, silo = ((2,), u32), ((), f32), ((), i32)
+    D, Hq, Hkv = CONFIG.d_head, CONFIG.n_heads, CONFIG.n_kv_heads
+    n_pages = SLOTS * PAGES_PER_SLOT
+    pool = ((n_pages, Hkv, PAGE, D), f32)
+    layers_pool = ((2, n_pages, Hkv, PAGE, D), f32)
+    tables, qs = ((SLOTS, PAGES_PER_SLOT), i32), ((SLOTS,), i32)
+
+    def attention(C):
+        return (paged_attention_pallas,
+                [((SLOTS, C, Hq, D), f32), pool, pool, tables, qs])
+
+    return {
+        "clip_sum": (clip_sum_pallas, [((N_SILOS, P), f32), sc]),
+        "clip_mask": (lambda g, s, kr, kx, kp, i, sig, b, lam:
+                      clip_mask_pallas(g, s, kr, kx, kp, i, N_SILOS, sig, b,
+                                       lam),
+                      [((P,), f32), sc, key, key, key, silo, sc, sc, sc]),
+        "noise_batch": (noise_batch_pallas,
+                        [((P,), f32), key, key, ((N_SILOS,), f32),
+                         ((N_SILOS,), f32), sc]),
+        "zsmask": (lambda g, kr, kx, i, sig, b:
+                   zsmask_pallas(g, kr, kx, i, N_SILOS, sig, b),
+                   [((P,), f32), key, key, silo, sc, sc]),
+        "clip_accumulate": (clip_accumulate,
+                            [((N_SILOS, P), f32), ((N_SILOS,), f32)]),
+        "per_example_sumsq": (per_example_sumsq, [((N_SILOS, P), f32)]),
+        "paged_attention_decode": attention(1),
+        "paged_attention_prefill_chunk": attention(128),
+        "paged_reset": (paged_reset_pallas,
+                        [layers_pool, layers_pool, ((PAGES_PER_SLOT,), i32)]),
+        "paged_rollback": (paged_rollback_pallas,
+                           [layers_pool, layers_pool, ((2,), i32),
+                            ((2,), i32)]),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "clip_sum", "clip_mask", "noise_batch", "zsmask", "clip_accumulate",
+    "per_example_sumsq", "paged_attention_decode",
+    "paged_attention_prefill_chunk", "paged_reset", "paged_rollback"])
+def test_kernel_compiles_for_v5e(kernel, one_chip, packed_size):
+    fn, shapes = _cases(packed_size)[kernel]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
