@@ -46,6 +46,11 @@ uncorrected remainder of xi_{t-1} simply persists in the model. That only
 *adds* residual noise, so the accountant's epsilon (computed for the fully
 corrected mechanism) remains a valid upper bound.
 
+Each stage runs under a ``jax.named_scope`` (``dp.norms``, ``dp.clip``,
+``dp.mask``, ``dp.noise``; ``dp.pack`` and ``dp.unpack`` on the flat-buffer
+conversions), so a profile attributes the engine's device time stage by
+stage.
+
 Tier placement stays in the callers: ``distributed/steps.py`` wraps these
 stages in vmap / scan / shard_map, ``core/tee/components.py`` invokes them
 per protocol message. Neither re-implements any of the math.
@@ -61,7 +66,7 @@ import numpy as np
 from repro.configs.base import PrivacyConfig
 from repro.core import clipping, flatbuf, masking, noise_correction
 from repro.core.barrier import BarrierKeys, dynamic_bound_from_percentiles
-from repro.core.flatbuf import PackedLayout
+from repro.core.flatbuf import PackedLayout, scoped
 from repro.core.noise_correction import NoiseState
 from repro.kernels.dispatch import REGISTRY
 from repro.kernels.dp_clip import ops as clip_ops
@@ -170,6 +175,7 @@ class DPPipeline:
             return self.full_active()  # legacy state: all silos contributed
         return pa
 
+    @scoped("dp.noise")
     def advance_state(self, keys: BarrierKeys, state: NoiseState,
                       active) -> NoiseState:
         """The state every tier carries to step t+1: the 32-byte key that
@@ -220,6 +226,7 @@ class DPPipeline:
             active=active,
             correction=self._admin_correction(template, state, bound))
 
+    @scoped("dp.noise")
     def admin_noise_tree(self, g_sum_tree, keys: BarrierKeys,
                          state: NoiseState, bound):
         """Central-tier aggregate noise under admin masks: regenerate the
@@ -237,16 +244,19 @@ class DPPipeline:
             g_sum_tree, xi)
 
     # -- stage: norms --------------------------------------------------------
+    @scoped("dp.norms")
     def norms(self, stacked) -> jax.Array:
         """Per-silo global norms off a stacked (n, P) packed buffer (padding
         is exactly zero, so one reduce replaces the per-leaf sumsq chain)."""
         g32 = stacked.astype(jnp.float32)
         return jnp.sqrt(jnp.sum(g32 * g32, axis=-1))
 
+    @scoped("dp.norms")
     def norm_tree(self, tree) -> jax.Array:
         return clipping.global_norm(tree)
 
     # -- stage: dynamic_bound ------------------------------------------------
+    @scoped("dp.clip")
     def dynamic_bound(self, norms, active, clip_key, fallback) -> jax.Array:
         """§4.3 percentile protocol over the *active* silos' norms; returns
         ``fallback`` (the carried bound) when dynamic clipping is off."""
@@ -256,9 +266,11 @@ class DPPipeline:
         return dynamic_bound_from_percentiles(pcts[None], self.priv, clip_key)
 
     # -- stage: clip_scale ---------------------------------------------------
+    @scoped("dp.clip")
     def clip_scale(self, norm, bound) -> jax.Array:
         return clipping.clip_scale(norm, bound)
 
+    @scoped("dp.clip")
     def clip_scales(self, norms, bound, active) -> jax.Array:
         """DP-SGD clip factors, zeroed for dropped silos — the single place
         deciding who contributes what weight to the aggregate."""
@@ -269,6 +281,7 @@ class DPPipeline:
         return scales * active.astype(scales.dtype)
 
     # -- stage: masked_aggregate ---------------------------------------------
+    @scoped("dp.mask")
     def masked_aggregate(self, stacked, scales) -> jax.Array:
         """sum_i scales_i * g_i over a stacked (n, P) buffer — one registry
         dispatch. Central tiers elide the zero-sum masks (they cancel in the
@@ -277,6 +290,7 @@ class DPPipeline:
         impl = self.policy.inner if self.policy.mode == "packed" else "auto"
         return clip_ops.clipped_sum(stacked, scales, impl=impl)
 
+    @scoped("dp.mask")
     def admin_closing_row(self, template, active, keys: BarrierKeys,
                           state: NoiseState, bound):
         """Admin-side construction of the closing silo's mask row — the one
@@ -299,6 +313,7 @@ class DPPipeline:
             correction=self._admin_correction(template, state, bound))
         return closing, row
 
+    @scoped("dp.noise")
     def noise_stream(self, key, silo) -> jax.Array:
         """This silo's (P,) standard-normal stream for a 32-byte step key —
         the exact values the fused graph would draw in-graph for xi (step
@@ -309,6 +324,7 @@ class DPPipeline:
         return _silo_stream(jnp.asarray(key), jnp.asarray(silo, jnp.int32),
                             idx)
 
+    @scoped("dp.mask")
     def silo_contribution(self, g_tree, silo, scale, active, keys: BarrierKeys,
                           state: NoiseState, bound, admin_row=None,
                           xi=None, xp=None):
@@ -428,6 +444,7 @@ class DPPipeline:
             noise_scale=s if static else s * gate,
             prev_noise_scale=s_prev, xi=xi, xp=xp)
 
+    @scoped("dp.unpack")
     def finalize(self, agg):
         """Aggregated contribution -> fp32 gradient pytree (unpacks packed
         buffers; perleaf aggregates are already trees)."""
@@ -436,6 +453,7 @@ class DPPipeline:
         return jax.tree.map(lambda x: x.astype(jnp.float32), agg)
 
     # -- stage: corrected_noise ----------------------------------------------
+    @scoped("dp.noise")
     def corrected_noise_packed(self, g_sum, keys: BarrierKeys,
                                state: NoiseState, bound, active) -> jax.Array:
         """Post-reduce corrected DP noise on a packed (P,) aggregate: the
@@ -465,6 +483,7 @@ class DPPipeline:
             g_sum, kx, state.prev_key, noise_scales, lam_gates, s_prev,
             use_prev=use_prev, impl=self.policy.inner)
 
+    @scoped("dp.noise")
     def corrected_noise_tree(self, g_sum_tree, keys: BarrierKeys,
                              state: NoiseState, bound, active):
         """Tree-level corrected noise for the central tiers. Packed policy

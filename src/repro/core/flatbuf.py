@@ -34,6 +34,21 @@ LANE = 128      # leaf starts stay lane-aligned (fp32 lane width)
 ALIGN = 1024    # total padded size divides every fused-kernel D-block
 
 
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``, a fresh
+    scope per call (one scope object is not re-entrant). The DP engine's
+    stages are scoped this way (``dp.pack``, ``dp.norms``, ``dp.clip``,
+    ``dp.mask``, ``dp.noise``, ``dp.unpack``), so their device ops carry the
+    stage in their HLO metadata and a profile attributes them to it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
@@ -84,6 +99,7 @@ def layout_of(tree, batch_dims: int = 0, lane: int = LANE,
     return _build_layout(treedef, shapes, dtypes, lane, align)
 
 
+@scoped("dp.pack")
 def pack(layout: PackedLayout, tree) -> jax.Array:
     """Flatten ``tree`` into one fp32 buffer of shape ``lead + (total,)``.
     Leading (batch) axes are inferred per leaf from the layout's element
@@ -106,6 +122,7 @@ def pack(layout: PackedLayout, tree) -> jax.Array:
     return buf
 
 
+@scoped("dp.unpack")
 def unpack(layout: PackedLayout, buf: jax.Array, dtype: Optional[Any] = None):
     """Inverse of :func:`pack` over the trailing axis. Leaves are cast to the
     layout's recorded dtypes, or to ``dtype`` when given."""

@@ -19,7 +19,11 @@ mesh-placement shims around its stages:
 
 All paths produce the same aggregate: sum_i clip(g_i) + sigma*C*(xi_t -
 lambda*xi_{t-1}), then update = aggregate / n_contributions via the
-optimizer. Every step takes an ``active: (n_silos,) bool`` participation set
+optimizer. Every tier runs the per-silo forward and backward pass under
+``jax.named_scope("train.fwd_bwd")`` and the update under
+``"train.optimizer"``, beside the engine's ``dp.*`` scopes, so a profile
+splits the step's device time between the model, the optimizer and the DP
+engine. Every step takes an ``active: (n_silos,) bool`` participation set
 (elastic silo membership — see runtime/elastic.py); ``None`` means all silos
 contribute.
 """
@@ -119,8 +123,9 @@ def _fused_grads(model: Model, priv: PrivacyConfig, params, batch, n_silos,
     pipe = DPPipeline(priv, layout, n_silos)
     active = _active_or_full(active, pipe)
 
-    losses, grads = jax.vmap(jax.value_and_grad(model.loss),
-                             in_axes=(None, 0))(params, silo_batches)
+    with jax.named_scope("train.fwd_bwd"):
+        losses, grads = jax.vmap(jax.value_and_grad(model.loss),
+                                 in_axes=(None, 0))(params, silo_batches)
     # packed outside the vmap (batched, pack's dynamic_update_slice writes
     # turn into a scatter) and behind a barrier: with the gradient program
     # fused into those writes the TPU compile takes ~20x longer (200 s
@@ -187,7 +192,8 @@ def _fused_grads_scan(model: Model, priv: PrivacyConfig, params, batch,
     def body(carry, xs):
         acc, loss_acc = carry
         b, gate = xs
-        loss, g = jax.value_and_grad(model.loss)(params, b)
+        with jax.named_scope("train.fwd_bwd"):
+            loss, g = jax.value_and_grad(model.loss)(params, b)
         norm = pipe.norm_tree(g)
         scale = pipe.clip_scale(norm, clip_bound) \
             if priv.enabled else jnp.asarray(1.0, jnp.float32)
@@ -242,7 +248,8 @@ def _barrier_grads(model: Model, priv: PrivacyConfig, mesh_cfg: MeshConfig,
         for ax in reversed(silo_axes):
             idx = idx + jax.lax.axis_index(ax) * mult
             mult *= jax.lax.axis_size(ax)
-        loss, g = jax.value_and_grad(model.loss)(params, batch_local)
+        with jax.named_scope("train.fwd_bwd"):
+            loss, g = jax.value_and_grad(model.loss)(params, batch_local)
         norm = pipe.norm_tree(g)
 
         if priv.dynamic_clip:
@@ -339,9 +346,11 @@ def build_train_step(model: Model, run_cfg: RunConfig, abstract_mesh=None,
 
         # the aggregate is divided by the silos that actually contributed
         n_contrib = jnp.maximum(jnp.sum(active.astype(jnp.float32)), 1.0)
-        grad = jax.tree.map(lambda g: g / n_contrib, noisy)
-        lr = lr_schedule(state.step)
-        new_params, new_opt = opt.update(state.params, state.opt_state, grad, lr)
+        with jax.named_scope("train.optimizer"):
+            grad = jax.tree.map(lambda g: g / n_contrib, noisy)
+            lr = lr_schedule(state.step)
+            new_params, new_opt = opt.update(state.params, state.opt_state,
+                                             grad, lr)
         gates = active.astype(jnp.float32)
         norm_mean = jnp.sum(norms.reshape(-1)[:n_silos] * gates) / n_contrib \
             if norms.shape[0] == n_silos else jnp.mean(norms)

@@ -178,13 +178,21 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
     """Paged-cache attention step: write the chunk's K/V through the block
     table, then read the whole slot back through the paged kernel. Returns
     (out, k_pages, v_pages). The write precedes the read, so query row c at
-    position q_start + c sees itself (mask ``kvpos <= q_start + c``)."""
+    position q_start + c sees itself (mask ``kvpos <= q_start + c``).
+
+    Named scopes for a profile: ``paged.qkv`` (the projections),
+    ``paged.kv_write`` and ``paged.attention`` (the paged kernel and the
+    output projection)."""
     from repro.kernels.paged_attention import ops as paged_ops
 
     B, S, _ = x.shape
-    q, k, v = _project_qkv(p, x, cfg, positions)
-    k_pages, v_pages = paged_kv_write(k_pages, v_pages, k, v, tables,
-                                      q_start, n_valid)
-    out = paged_ops.paged_attention(q, k_pages, v_pages, tables, q_start)
-    out = out.astype(x.dtype).reshape(B, S, cfg.attn_inner)
-    return out @ p["wo"].astype(x.dtype), k_pages, v_pages
+    with jax.named_scope("paged.qkv"):
+        q, k, v = _project_qkv(p, x, cfg, positions)
+    with jax.named_scope("paged.kv_write"):
+        k_pages, v_pages = paged_kv_write(k_pages, v_pages, k, v, tables,
+                                          q_start, n_valid)
+    with jax.named_scope("paged.attention"):
+        out = paged_ops.paged_attention(q, k_pages, v_pages, tables, q_start)
+        out = out.astype(x.dtype).reshape(B, S, cfg.attn_inner)
+        out = out @ p["wo"].astype(x.dtype)
+    return out, k_pages, v_pages

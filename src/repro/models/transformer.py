@@ -144,16 +144,20 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
 
 def layer_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
                       tables, q_start, n_valid):
+    with jax.named_scope("paged.qkv"):
+        y = rmsnorm(p["ln1"], x)
     h, k_pages, v_pages = attn.attn_apply_paged(
-        p["attn"], rmsnorm(p["ln1"], x), cfg, positions, k_pages, v_pages,
-        tables, q_start, n_valid)
+        p["attn"], y, cfg, positions, k_pages, v_pages, tables, q_start,
+        n_valid)
     x = x + h
-    y = rmsnorm(p["ln2"], x)
-    if cfg.is_moe():
-        f, _ = moe_mod.moe_apply(p["moe"], y, cfg, cfg.moe_capacity_factor)
-    else:
-        f = swiglu_apply(p["ffn"], y)
-    return x + f, k_pages, v_pages
+    with jax.named_scope("paged.mlp"):
+        y = rmsnorm(p["ln2"], x)
+        if cfg.is_moe():
+            f, _ = moe_mod.moe_apply(p["moe"], y, cfg, cfg.moe_capacity_factor)
+        else:
+            f = swiglu_apply(p["ffn"], y)
+        x = x + f
+    return x, k_pages, v_pages
 
 
 def forward_paged(params, cfg: ModelConfig, tokens, pages: dict, tables,
@@ -172,8 +176,14 @@ def forward_paged(params, cfg: ModelConfig, tokens, pages: dict, tables,
     every chunk position — the speculative-verify read-out, where position
     c scores the token *following* tokens[:, c]. Both modes run the same
     layer stack, so a chunk-shaped "all" graph is the only addition the
-    speculative scheduler needs for verification."""
-    x = params["embed"].astype(compute_dtype)[tokens]
+    speculative scheduler needs for verification.
+
+    Device ops carry named scopes for a profile: ``paged.qkv``,
+    ``paged.kv_write``, ``paged.attention`` and ``paged.mlp`` in each layer,
+    ``paged.head`` on both uses of the vocabulary table (the embedding
+    lookup, and the final norm with the read-out)."""
+    with jax.named_scope("paged.head"):
+        x = params["embed"].astype(compute_dtype)[tokens]
     B, S = tokens.shape
     positions = q_start[:, None] + jnp.arange(S)[None, :]
 
@@ -185,17 +195,17 @@ def forward_paged(params, cfg: ModelConfig, tokens, pages: dict, tables,
 
     x, (k_pages, v_pages) = jax.lax.scan(
         body, x, (params["layers"], pages["k_pages"], pages["v_pages"]))
-    x = rmsnorm(params["ln_f"], x)
-    head = params.get("lm_head", None)
-    if head is None:
-        head = params["embed"].T
-    if logits_mode == "all":
-        logits = x @ head.astype(x.dtype)                      # (B, C, V)
-        return logits, {"k_pages": k_pages, "v_pages": v_pages}
-    last = jnp.clip(n_valid - 1, 0, S - 1)                     # (B,)
-    x = jnp.take_along_axis(x, last[:, None, None], axis=1)    # (B, 1, D)
-    logits = (x @ head.astype(x.dtype))[:, 0]
-    return logits, {"k_pages": k_pages, "v_pages": v_pages}
+    pages = {"k_pages": k_pages, "v_pages": v_pages}
+    with jax.named_scope("paged.head"):
+        x = rmsnorm(params["ln_f"], x)
+        head = params.get("lm_head", None)
+        if head is None:
+            head = params["embed"].T
+        if logits_mode == "all":
+            return x @ head.astype(x.dtype), pages              # (B, C, V)
+        last = jnp.clip(n_valid - 1, 0, S - 1)                  # (B,)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (B, 1, D)
+        return (x @ head.astype(x.dtype))[:, 0], pages
 
 
 def prefill(params, cfg: ModelConfig, batch: dict, cache, compute_dtype=jnp.bfloat16):

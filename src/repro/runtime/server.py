@@ -44,6 +44,12 @@ class Request:
     # higher-priority request (the preempted request is re-queued at its
     # original position and restored by recompute — token-identical output)
     priority: int = 0
+    # host-clock stamps (``time.perf_counter()``) set by the
+    # ContinuousServer: submitted, first admitted to a slot, first token
+    # on the host
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first_token: Optional[float] = None
 
 
 @dataclass
@@ -68,6 +74,14 @@ class ServerStats:
     # graceful degradation: slots evicted under pool pressure to admit a
     # higher-priority request (each restored later by recompute)
     preemptions: int = 0
+    # prefill chunk calls: rows holding prompt tokens / rows the chunk
+    # program computed (n_slots x chunk per call)
+    prefill_rows_valid: int = 0
+    prefill_rows_computed: int = 0
+    # paged-attention calls: table pages covering each valid slot's tokens
+    # after the call / table pages the kernel's grid visits
+    pages_live: int = 0
+    pages_walked: int = 0
 
     @property
     def utilization(self) -> float:

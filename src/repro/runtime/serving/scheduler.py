@@ -44,6 +44,17 @@ lives entirely in the block tables, lengths and validity masks, which are
 data. Page allocations are bucketed to powers of two so recycled claims fit
 each other's freed runs.
 
+Every tick is traced with ``jax.profiler.TraceAnnotation`` spans, inert
+unless a profiler is running: ``serve.tick`` around ``step``; inside it
+``serve.admit``, ``serve.decode`` and ``serve.prefill``; inside the last two
+``serve.prepare`` (host token and validity arrays), ``serve.dispatch`` (the
+jitted call, argument transfer included), ``serve.fetch`` (logits to the
+host, waiting for the device) and ``serve.sample`` (argmax, append, finish).
+A ``serve.dispatch`` span carries its call's increments of the
+``ServerStats`` row and page counters as arguments, and a
+``serve.first_token`` mark carries each request's admission wait and
+prefill time (ms).
+
 Token-for-token equivalence with the wave baseline (greedy argmax over the
 same model) is a test invariant, not an aspiration: ``tests/test_serving.py``
 asserts it under randomized admission/finish orders, and asserts the
@@ -54,12 +65,14 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import time
 import warnings
 from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.server import Request, ServerStats
 from repro.runtime.serving.paged_cache import PagePool
@@ -200,6 +213,7 @@ class ContinuousServer:
             raise ValueError(
                 f"request {req.rid} exceeds max_len {self.max_len}")
         req.submit_step = self.clock
+        req.t_submit = time.perf_counter()
         self.queues.setdefault(req.tenant, collections.deque()).append(
             (self._seq, req))
         self._seq += 1
@@ -271,6 +285,8 @@ class ContinuousServer:
                 self._deficit.pop(t_star, None)
             S0 = len(shared) * self.pool.page_size
             self.stats.shared_prompt_tokens += S0
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
             self.slots[i] = _Slot(req, pos=S0, seq=seq,
                                   prompt=np.asarray(eff, np.int32))
 
@@ -316,6 +332,13 @@ class ContinuousServer:
         req = self.slots[i].req
         req.generated.append(tok)
         self.stats.useful_tokens += 1
+        if req.t_first_token is None:
+            req.t_first_token = t = time.perf_counter()
+            # a mark in the profile: its arguments carry the stamps
+            with TraceAnnotation("serve.first_token",
+                                 wait_ms=1e3 * (req.t_admit - req.t_submit),
+                                 prefill_ms=1e3 * (t - req.t_admit)):
+                pass
         if len(req.generated) >= req.max_new_tokens or \
                 (req.eos_id is not None and tok == req.eos_id):
             self._finish(i, req)
@@ -323,30 +346,64 @@ class ContinuousServer:
         return False
 
     # ------------------------------------------------------------------ step
+    def _dispatch(self, kernel_calls, prefill: bool = False):
+        """Count one program call and open its ``serve.dispatch`` span.
+        ``kernel_calls``: the ``(lengths, n_valid)`` of each pass of the
+        call through the paged kernel (one, or one per step of the draft's
+        proposal scan). Live pages cover each valid slot's cached plus new
+        tokens; walked pages are what the kernel's grid visits, the whole
+        table passed to it. ``prefill``: a chunk-program call, whose rows
+        count too."""
+        P = self.pool.page_size
+        rows, nP = self.pool.tables.shape
+        live = sum(int(np.sum(-(-(lengths + n_valid) // P)[n_valid > 0]))
+                   for lengths, n_valid in kernel_calls)
+        walked = len(kernel_calls) * rows * nP
+        self.stats.pages_live += live
+        self.stats.pages_walked += walked
+        rows_valid = rows_computed = 0
+        if prefill:
+            rows_valid = int(kernel_calls[0][1].sum())
+            rows_computed = rows * self.prefill_chunk
+            self.stats.prefill_rows_valid += rows_valid
+            self.stats.prefill_rows_computed += rows_computed
+        return TraceAnnotation("serve.dispatch", pages_live=live,
+                               pages_walked=walked, rows_valid=rows_valid,
+                               rows_computed=rows_computed)
+
     def _run_prefill_chunks(self) -> None:
         C = self.prefill_chunk
-        idx = [i for i, s in enumerate(self.slots)
-               if s is not None and s.pos < len(s.prompt)]
-        if not idx:
-            return
-        tokens = np.zeros((self.n_slots, C), np.int32)
-        n_valid = np.zeros((self.n_slots,), np.int32)
-        for i in idx:
-            s = self.slots[i]
-            chunk = s.prompt[s.pos:s.pos + C]
-            tokens[i, :len(chunk)] = chunk
-            n_valid[i] = len(chunk)
+        with TraceAnnotation("serve.prepare"):
+            idx = [i for i, s in enumerate(self.slots)
+                   if s is not None and s.pos < len(s.prompt)]
+            if not idx:
+                return
+            tokens = np.zeros((self.n_slots, C), np.int32)
+            n_valid = np.zeros((self.n_slots,), np.int32)
+            for i in idx:
+                s = self.slots[i]
+                chunk = s.prompt[s.pos:s.pos + C]
+                tokens[i, :len(chunk)] = chunk
+                n_valid[i] = len(chunk)
+        lengths = self.pool.lengths
         if self.speculative:
             # keep the draft cache in lockstep: same tokens into the draft
             # pool, logits discarded — this is what makes a later sharer's
             # draft cache warm over shared prefix pages too
-            _, self.pool.draft_pages = self._draft_fn(
-                self.draft_params, tokens, self.pool.draft_pages,
-                self.pool.tables, self.pool.lengths, n_valid)
-        logits, self.pool.pages = self._step_fn(
-            self.params, tokens, self.pool.pages,
-            self.pool.tables, self.pool.lengths, n_valid)
-        logits = np.asarray(logits)
+            with self._dispatch([(lengths, n_valid)]):
+                _, self.pool.draft_pages = self._draft_fn(
+                    self.draft_params, tokens, self.pool.draft_pages,
+                    self.pool.tables, lengths, n_valid)
+        with self._dispatch([(lengths, n_valid)], prefill=True):
+            logits, self.pool.pages = self._step_fn(
+                self.params, tokens, self.pool.pages,
+                self.pool.tables, lengths, n_valid)
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
+            self._sample_prefill(idx, n_valid, logits)
+
+    def _sample_prefill(self, idx, n_valid, logits) -> None:
         for i in idx:
             s = self.slots[i]
             s.pos += int(n_valid[i])
@@ -364,19 +421,26 @@ class ContinuousServer:
                     s.pending = tok
 
     def _run_decode(self) -> None:
-        idx = [i for i, s in enumerate(self.slots)
-               if s is not None and s.pending is not None]
-        if not idx:
-            return
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        n_valid = np.zeros((self.n_slots,), np.int32)
-        for i in idx:
-            tokens[i, 0] = self.slots[i].pending
-            n_valid[i] = 1
-        logits, self.pool.pages = self._step_fn(
-            self.params, tokens, self.pool.pages,
-            self.pool.tables, self.pool.lengths, n_valid)
-        logits = np.asarray(logits)
+        with TraceAnnotation("serve.prepare"):
+            idx = [i for i, s in enumerate(self.slots)
+                   if s is not None and s.pending is not None]
+            if not idx:
+                return
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            n_valid = np.zeros((self.n_slots,), np.int32)
+            for i in idx:
+                tokens[i, 0] = self.slots[i].pending
+                n_valid[i] = 1
+        with self._dispatch([(self.pool.lengths, n_valid)]):
+            logits, self.pool.pages = self._step_fn(
+                self.params, tokens, self.pool.pages,
+                self.pool.tables, self.pool.lengths, n_valid)
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
+            self._sample_decode(idx, logits)
+
+    def _sample_decode(self, idx, logits) -> None:
         for i in idx:
             self.pool.lengths[i] += 1
             if self.trace_logits:
@@ -395,34 +459,49 @@ class ContinuousServer:
         allocation covers exactly prompt + max_new tokens, so speculating
         past the budget would write K/V past the slot's page capacity."""
         k = self.spec_k
-        idx = [i for i, s in enumerate(self.slots)
-               if s is not None and s.pending is not None]
-        if not idx:
-            return
-        k_eff = {i: min(k, self.slots[i].req.max_new_tokens
-                        - len(self.slots[i].req.generated)) for i in idx}
-        base = self.pool.lengths.copy()
-        props = {i: [self.slots[i].pending] for i in idx}
-        t0 = np.zeros((self.n_slots,), np.int32)
-        keff_arr = np.zeros((self.n_slots,), np.int32)
-        for i in idx:
-            t0[i] = props[i][0]
-            keff_arr[i] = k_eff[i]
-        drafted, self.pool.draft_pages = self._propose_fn(
-            self.draft_params, self.pool.draft_pages, self.pool.tables,
-            base, t0, keff_arr)
-        drafted = np.asarray(drafted)  # (n_slots, k-1); cols >= k_eff-1 junk
-        for i in idx:
-            props[i] += [int(t) for t in drafted[i, :k_eff[i] - 1]]
-        tokens = np.zeros((self.n_slots, k), np.int32)
-        n_valid = np.zeros((self.n_slots,), np.int32)
-        for i in idx:
-            tokens[i, :k_eff[i]] = props[i]
-            n_valid[i] = k_eff[i]
-        vlogits, self.pool.pages = self._verify_fn(
-            self.params, tokens, self.pool.pages,
-            self.pool.tables, base, n_valid)
-        vlogits = np.asarray(vlogits)  # (n_slots, k, V)
+        with TraceAnnotation("serve.prepare"):
+            idx = [i for i, s in enumerate(self.slots)
+                   if s is not None and s.pending is not None]
+            if not idx:
+                return
+            k_eff = {i: min(k, self.slots[i].req.max_new_tokens
+                            - len(self.slots[i].req.generated)) for i in idx}
+            base = self.pool.lengths.copy()
+            props = {i: [self.slots[i].pending] for i in idx}
+            t0 = np.zeros((self.n_slots,), np.int32)
+            keff_arr = np.zeros((self.n_slots,), np.int32)
+            for i in idx:
+                t0[i] = props[i][0]
+                keff_arr[i] = k_eff[i]
+        # draft step j writes one token for slots with j < k_eff - 1
+        with self._dispatch([(base + j, (j < keff_arr - 1).astype(np.int32))
+                             for j in range(k - 1)]):
+            drafted, self.pool.draft_pages = self._propose_fn(
+                self.draft_params, self.pool.draft_pages, self.pool.tables,
+                base, t0, keff_arr)
+        with TraceAnnotation("serve.fetch"):
+            # (n_slots, k-1); cols >= k_eff-1 junk
+            drafted = np.asarray(drafted)
+        with TraceAnnotation("serve.prepare"):
+            for i in idx:
+                props[i] += [int(t) for t in drafted[i, :k_eff[i] - 1]]
+            tokens = np.zeros((self.n_slots, k), np.int32)
+            n_valid = np.zeros((self.n_slots,), np.int32)
+            for i in idx:
+                tokens[i, :k_eff[i]] = props[i]
+                n_valid[i] = k_eff[i]
+        with self._dispatch([(base, n_valid)]):
+            vlogits, self.pool.pages = self._verify_fn(
+                self.params, tokens, self.pool.pages,
+                self.pool.tables, base, n_valid)
+        with TraceAnnotation("serve.fetch"):
+            vlogits = np.asarray(vlogits)  # (n_slots, k, V)
+        with TraceAnnotation("serve.sample"):
+            self._accept(idx, k_eff, base, props, vlogits)
+
+    def _accept(self, idx, k_eff, base, props, vlogits) -> None:
+        """Greedy accept of each slot's proposals against the verifier's
+        argmax, then rollback of the rejected tail."""
         for i in idx:
             ke, p = k_eff[i], props[i]
             # row j scores the token following p[j]; g[j] is therefore the
@@ -462,18 +541,22 @@ class ContinuousServer:
         mid-prefill slots are the measured tax) — times ``spec_k`` in
         speculative mode, where every slot had k chances; prefill compute
         itself is free, like the wave's uncharged prefill call."""
-        self.clock += 1
-        before = self.stats.useful_tokens
-        self._admit()
-        if self.speculative:
-            self._run_spec_decode()
-        else:
-            self._run_decode()
-        self._run_prefill_chunks()
-        if self.stats.useful_tokens > before:
-            self.stats.decode_steps += 1
-            self.stats.slot_tokens += self.n_slots * (
-                self.spec_k if self.speculative else 1)
+        with TraceAnnotation("serve.tick"):
+            self.clock += 1
+            before = self.stats.useful_tokens
+            with TraceAnnotation("serve.admit"):
+                self._admit()
+            with TraceAnnotation("serve.decode"):
+                if self.speculative:
+                    self._run_spec_decode()
+                else:
+                    self._run_decode()
+            with TraceAnnotation("serve.prefill"):
+                self._run_prefill_chunks()
+            if self.stats.useful_tokens > before:
+                self.stats.decode_steps += 1
+                self.stats.slot_tokens += self.n_slots * (
+                    self.spec_k if self.speculative else 1)
 
     def run_until_drained(self, max_steps: int = 100_000) -> ServerStats:
         while (self.queued or any(s is not None for s in self.slots)) \

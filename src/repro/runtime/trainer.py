@@ -5,6 +5,12 @@ The inner step is the jitted CITADEL++ train step (distributed/steps.py); this
 loop owns everything jit can't: the accountant (its state must survive
 restarts — the privacy guarantee composes over *all* steps ever taken), the
 data-iterator state, checkpoint cadence, and wall-clock policies.
+
+Each step's host work is traced with ``jax.profiler.TraceAnnotation`` spans,
+inert unless a profiler is running: ``train.next_batch``, ``train.dispatch``
+(the jitted step call), ``train.ledger`` (the privacy ledger's record and
+epsilon reads), ``train.flush`` (metrics to the host) and
+``train.checkpoint``.
 """
 from __future__ import annotations
 
@@ -16,6 +22,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import checkpointer
 from repro.configs.base import RunConfig
@@ -180,42 +187,50 @@ class Trainer:
         """Convert pending on-device metric entries to host floats in one
         transfer. Keeping per-step metrics on-device avoids a device sync
         every step (the jitted step stays fully async between boundaries).
-        In adaptive straggler mode this is also where the policy observes
-        time: the transfer drains the dispatch queue, so window wall time /
-        window steps is the honest per-step time."""
+        This is also where step time is observed: the transfer drains the
+        dispatch queue, so window wall time / window steps is the honest
+        per-step time. Each flushed entry's ``step_time_s`` is that value;
+        in adaptive straggler mode it also re-anchors the policy's EMA."""
         if not self._pending:
             return
-        n = len(self._pending)
-        host = jax.device_get(self._pending)
-        self._pending.clear()
-        for entry in host:
-            self.metrics_log.append({
-                k: float(v) if isinstance(v, (np.ndarray, np.floating))
-                else v
-                for k, v in entry.items()})
-        if self.tcfg.step_deadline_s is None and self._window_t0 is not None:
-            # authoritative amortized step time for this window re-anchors
-            # the adaptive EMA; per-step dispatch dts (observed once
-            # calibrated) then catch individual stalls via back-pressure
-            self.straggler.calibrate((time.time() - self._window_t0) / n)
-        self._window_t0 = time.time()
+        with TraceAnnotation("train.flush"):
+            n = len(self._pending)
+            host = jax.device_get(self._pending)
+            self._pending.clear()
+            step_time_s = None if self._window_t0 is None \
+                else (time.time() - self._window_t0) / n
+            for entry in host:
+                self.metrics_log.append({
+                    k: float(v) if isinstance(v, (np.ndarray, np.floating))
+                    else v
+                    for k, v in entry.items()} | {"step_time_s": step_time_s})
+            if self.tcfg.step_deadline_s is None and step_time_s is not None:
+                # authoritative amortized step time for this window
+                # re-anchors the adaptive EMA; per-step enqueue times
+                # (observed once calibrated) then catch individual stalls
+                # via back-pressure
+                self.straggler.calibrate(step_time_s)
+            self._window_t0 = time.time()
 
     # -- checkpointing -----------------------------------------------------
     def _save(self, state, step: int):
-        self._flush_metrics()
-        if not self.tcfg.checkpoint_dir:
-            return
-        extra = {
-            "accountant": self.accountant.state_dict() if self.accountant else None,
-            "batch_state": (self.batch_state.state_dict()
-                            if self.batch_state is not None else None),
-            # metrics history must survive preemption/restart, or the run's
-            # loss/epsilon curves silently truncate at the restore point
-            "metrics_log": list(self.metrics_log),
-        }
-        checkpointer.save(self.tcfg.checkpoint_dir, step, state, extra)
-        checkpointer.garbage_collect(self.tcfg.checkpoint_dir,
-                                     self.tcfg.keep_checkpoints)
+        with TraceAnnotation("train.checkpoint"):
+            self._flush_metrics()
+            if not self.tcfg.checkpoint_dir:
+                return
+            extra = {
+                "accountant": (self.accountant.state_dict()
+                               if self.accountant else None),
+                "batch_state": (self.batch_state.state_dict()
+                                if self.batch_state is not None else None),
+                # metrics history must survive preemption/restart, or the
+                # run's loss/epsilon curves silently truncate at the restore
+                # point
+                "metrics_log": list(self.metrics_log),
+            }
+            checkpointer.save(self.tcfg.checkpoint_dir, step, state, extra)
+            checkpointer.garbage_collect(self.tcfg.checkpoint_dir,
+                                         self.tcfg.keep_checkpoints)
 
     def try_restore(self, state):
         """Resume from the latest complete checkpoint if one exists."""
@@ -305,7 +320,8 @@ class Trainer:
                 # there is nothing DP allows to aggregate
                 break
 
-            batch = self.next_batch()
+            with TraceAnnotation("train.next_batch"):
+                batch = self.next_batch()
             if self._window_t0 is None:
                 self._window_t0 = time.time()
             self._step = step
@@ -313,36 +329,41 @@ class Trainer:
                 # fused tiers: simulated per-silo latencies for attribution
                 self.telemetry.observe_all(self.silo_latency_hook(step))
             t0 = time.time()
-            if active is None:
-                # statically all-active: omit the argument so the engine
-                # traces its fixed-ring fast path (no gating/ring work —
-                # bit-identical output)
-                state, metrics = self._jit_step(state, batch, root_key)
-            else:
-                state, metrics = self._jit_step(state, batch, root_key,
-                                                jnp.asarray(active))
+            with TraceAnnotation("train.dispatch"):
+                if active is None:
+                    # statically all-active: omit the argument so the engine
+                    # traces its fixed-ring fast path (no gating/ring work —
+                    # bit-identical output)
+                    state, metrics = self._jit_step(state, batch, root_key)
+                else:
+                    state, metrics = self._jit_step(state, batch, root_key,
+                                                    jnp.asarray(active))
+                if self.tcfg.step_deadline_s is not None:
+                    # a hard deadline needs true step time -> block per step
+                    jax.block_until_ready(metrics)
             if self.tcfg.step_deadline_s is not None:
-                # a hard deadline needs true step time -> block per step
-                jax.block_until_ready(metrics)
-            dt = time.time() - t0
-            if self.tcfg.step_deadline_s is not None:
-                self.straggler.observe(dt)
+                self.straggler.observe(time.time() - t0)
             elif self.straggler.calibrated:
-                # async mode: metrics stay on-device (no per-step host sync).
-                # Dispatch wall time still surfaces device stalls (dispatch
-                # blocks once the queue backs up), so use it for *flagging*
-                # only — the EMA baseline is anchored exclusively by
-                # calibrate() at flush boundaries, or the near-zero
-                # post-drain dts would decay it into spurious flags
-                self.straggler.observe(dt, update_baseline=False)
-            entry = {"step": step, **metrics, "step_time_s": dt}
+                # async mode: metrics stay on-device (no per-step host sync),
+                # so the call returns once the step is enqueued. The enqueue
+                # time still surfaces device stalls (it blocks once the
+                # queue backs up), so use it for *flagging* only — the EMA
+                # baseline is anchored exclusively by calibrate() at flush
+                # boundaries, or the near-zero post-drain enqueue times
+                # would decay it into spurious flags
+                enqueue_s = time.time() - t0
+                self.straggler.observe(enqueue_s, update_baseline=False)
+            entry = {"step": step, **metrics}
             if self.accountant:
-                # per-step participation bitmask: the ledger attributes this
-                # step's privacy loss to exactly the silos that contributed
-                self.accountant.record(active)
-                entry["epsilon"] = self.accountant.epsilon()
-                entry["epsilon_per_silo"] = self.accountant.epsilon_per_silo()
-                self._enforce_budgets(step + 1)
+                with TraceAnnotation("train.ledger"):
+                    # per-step participation bitmask: the ledger attributes
+                    # this step's privacy loss to exactly the silos that
+                    # contributed
+                    self.accountant.record(active)
+                    entry["epsilon"] = self.accountant.epsilon()
+                    entry["epsilon_per_silo"] = \
+                        self.accountant.epsilon_per_silo()
+                    self._enforce_budgets(step + 1)
             self._pending.append(entry)
             step += 1
             if len(self._pending) >= max(self.tcfg.metrics_flush_every, 1):
