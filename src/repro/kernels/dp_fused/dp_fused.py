@@ -27,10 +27,29 @@ Two kernel families over buffers produced by ``core/flatbuf.py``:
 Scalars ride in SMEM. Counters are global element indices, so results are
 independent of the blocking and bit-identical to the jnp oracles in
 ``ref.py`` for any block size.
+
+Block geometry of ``noise_batch_pallas``. An f32 vreg holds (8, 128)
+elements; a one-row ``(1, block_d)`` block fills one of its 8 sublanes, so
+every threefry and Box-Muller op did 8x the work it needed, and a grid step
+costs about 0.23 us on a v5e whatever it holds. The kernel therefore views
+the ``(P,)`` buffer as ``(P // 128, 128)`` -- free, since a 1-D f32 array's
+``T(1024)`` tiling is laid out byte for byte as ``(8, 128)`` tiles (the
+``(P // 1024, 1024)`` view is not: it lowers to a relayout copy) -- and
+streams it in ``(NOISE_BLOCK_ROWS, 128)`` blocks, a few hundred grid steps
+at the packed size, with a ragged last block (``P // 128`` has no large
+power-of-two factor; Pallas drops the out-of-range writes). Inside a block
+a ``fori_loop`` walks ``NOISE_STRIP_ROWS``-row strips, so one strip's
+threefry state stays in vregs instead of a whole block's temporaries
+spilling to VMEM. A strip of 64 rows gives each op 8 independent vregs,
+which hides the latency of threefry's serial add-rotate-xor chain: on a
+v5e at the 2-silo packed size, 8-row strips took 61 ms and 64-row strips
+25 ms, at any block of 256-4,096 rows. The counter of element (row, lane)
+is still ``row * 128 + lane``, its global index.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +57,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.zsmask.threefry import normal_pair
+
+
+LANES = 128             # a vreg's lane width
+NOISE_BLOCK_ROWS = 2048  # rows of 128 elements per noise_batch grid step
+NOISE_STRIP_ROWS = 64    # rows per strip of the noise_batch inner loop
 
 
 def _block_b_for(B: int) -> int:
@@ -220,69 +244,92 @@ def clip_mask_pallas(g, scale, key_r, key_xi, prev_key, silo, n_silos: int,
 
 
 def _noise_batch_kernel(ints_ref, flts_ref, scales_ref, lams_ref, g_ref,
-                        o_ref, *, block_d: int, n_silos: int, use_prev: bool):
-    di = pl.program_id(0)
+                        o_ref, *, block_rows: int, strip_rows: int,
+                        n_silos: int, use_prev: bool):
+    bi = pl.program_id(0)
     key_x0 = ints_ref[0].astype(jnp.uint32)
     key_x1 = ints_ref[1].astype(jnp.uint32)
     key_p0 = ints_ref[2].astype(jnp.uint32)
     key_p1 = ints_ref[3].astype(jnp.uint32)
     s_prev = flts_ref[0]  # std of every silo's step-(t-1) share
 
-    base = jnp.asarray(di * block_d).astype(jnp.uint32)
-    idx = base + jax.lax.broadcasted_iota(jnp.uint32, (1, block_d), 1)
+    shape = (strip_rows, LANES)
+    offset = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0) * LANES
+              + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+    block_base = bi.astype(jnp.uint32) * jnp.uint32(block_rows * LANES)
 
-    def stream(k0, k1, sid):
-        z0, _ = normal_pair(k0, k1, idx,
-                            sid.astype(jnp.uint32) + jnp.zeros_like(idx))
-        return z0
+    def one_strip(j, carry):
+        r0 = pl.multiple_of(j * strip_rows, strip_rows)
+        # the counter is the element's global index in the (P,) buffer
+        idx = block_base + r0.astype(jnp.uint32) * LANES + offset
 
-    def add_share(i, out):
-        # each share is built exactly as the per-silo clip_mask launch did
-        # on a zeros buffer — (0 + s_i*xi_i) - lam_i*(s_prev*xp_i) — then
-        # folded on in silo order: the left fold every tier bit-matches
-        share = 0.0 + scales_ref[i] * stream(key_x0, key_x1, i)
-        if use_prev:
-            share = share - lams_ref[i] * (s_prev * stream(key_p0, key_p1, i))
-        return out + share
+        def stream(k0, k1, sid):
+            z0, _ = normal_pair(k0, k1, idx,
+                                sid.astype(jnp.uint32) + jnp.zeros_like(idx))
+            return z0
 
-    out = g_ref[...].astype(jnp.float32)
-    out = jax.lax.fori_loop(0, n_silos, add_share, out)
-    o_ref[...] = out
+        def add_share(i, out):
+            # each share is built exactly as the per-silo clip_mask launch
+            # did on a zeros buffer — (0 + s_i*xi_i) - lam_i*(s_prev*xp_i) —
+            # then folded on in silo order: the left fold every tier
+            # bit-matches
+            share = 0.0 + scales_ref[i] * stream(key_x0, key_x1, i)
+            if use_prev:
+                share = share - lams_ref[i] * (s_prev
+                                               * stream(key_p0, key_p1, i))
+            return out + share
+
+        rows = (pl.ds(r0, strip_rows), slice(None))
+        out = g_ref[rows].astype(jnp.float32)
+        o_ref[rows] = jax.lax.fori_loop(0, n_silos, add_share, out)
+        return carry
+
+    jax.lax.fori_loop(0, block_rows // strip_rows, one_strip, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("use_prev", "block_d",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_prev", "block_rows",
+                                             "strip_rows", "interpret"))
 def noise_batch_pallas(g_sum, key_xi, prev_key, noise_scales, lam_gates,
                        prev_noise_scale, use_prev: bool = True,
-                       block_d: int = 1024, interpret: bool = False):
+                       block_rows: int = NOISE_BLOCK_ROWS,
+                       strip_rows: int = NOISE_STRIP_ROWS,
+                       interpret: bool = False):
     """g_sum: packed (P,) aggregate; key_xi/prev_key: (2,) uint32;
     noise_scales/lam_gates: per-silo (n,) fp32 (participation gates folded
     in by the caller). Returns fp32
     ``g_sum + sum_i (s_i*xi_t^i - lam_i*s_prev*xi_{t-1}^i)`` with every
-    stream regenerated inside VMEM — one launch for all n silos."""
+    stream regenerated inside VMEM — one launch for all n silos.
+
+    The buffer is streamed as its free ``(P // 128, 128)`` view in
+    ``(block_rows, 128)`` blocks (the last one ragged), each walked in
+    ``strip_rows``-row strips (see the module docstring)."""
     P = g_sum.shape[0]
     n_silos = noise_scales.shape[0]
-    block_d = min(block_d, P)
-    assert P % block_d == 0, (P, block_d)
+    assert P % LANES == 0, P
+    rows = P // LANES
+    block_rows = min(block_rows, rows)
+    strip_rows = math.gcd(strip_rows, block_rows)
     ints = jnp.stack([
         key_xi[0].astype(jnp.int32), key_xi[1].astype(jnp.int32),
         prev_key[0].astype(jnp.int32), prev_key[1].astype(jnp.int32)])
     flts = jnp.asarray(prev_noise_scale, jnp.float32)[None]
+    block = pl.BlockSpec((block_rows, LANES), lambda r: (r, 0))
 
     out = pl.pallas_call(
-        functools.partial(_noise_batch_kernel, block_d=block_d,
-                          n_silos=n_silos, use_prev=use_prev),
-        grid=(P // block_d,),
+        functools.partial(_noise_batch_kernel, block_rows=block_rows,
+                          strip_rows=strip_rows, n_silos=n_silos,
+                          use_prev=use_prev),
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_d), lambda d: (0, d)),
+            block,
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda d: (0, d)),
-        out_shape=jax.ShapeDtypeStruct((1, P), jnp.float32),
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
     )(ints, flts, noise_scales.astype(jnp.float32),
-      lam_gates.astype(jnp.float32), g_sum[None])
-    return out[0]
+      lam_gates.astype(jnp.float32), g_sum.reshape(rows, LANES))
+    return out.reshape(P)

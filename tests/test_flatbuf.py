@@ -188,11 +188,37 @@ def test_noise_batch_pallas_matches_ref_any_blocking(n):
     noise_scales, lam_gates, s_prev = _noise_batch_case(n)
     ref_out = fref.noise_batch_ref(g, KEY_XI, KEY_P, noise_scales, lam_gates,
                                    s_prev)
-    for block in (1024, 2048, 4096):
+    for rows in (8, 16, 32):
         pal = noise_batch_pallas(g, KEY_XI, KEY_P, noise_scales, lam_gates,
-                                 s_prev, block_d=block, interpret=True)
+                                 s_prev, block_rows=rows, interpret=True)
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref_out),
                                    atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [2, 44])
+@pytest.mark.parametrize("rows,block_rows,strip_rows", [
+    (40, 16, 8),     # 2 full blocks + a ragged one of 8 rows
+    (72, 64, 16),    # ragged last block of 8 rows, 16-row strips
+    (88, 32, 16),    # 11 x 8 rows, like the real 1,812,088: no power of 2
+    (24, 2048, 16),  # one block of the whole buffer; strips fall to 8
+])
+def test_noise_batch_pallas_ragged_blocks_bit_match_ref(n, rows, block_rows,
+                                                        strip_rows):
+    """The (P // 128, 128) view in (block_rows, 128) blocks, walked in
+    strips, with a ragged last block: every value equals the jitted oracle
+    and the 8-row geometry bit for bit (counters are global indices)."""
+    from repro.kernels.dp_fused.dp_fused import noise_batch_pallas
+
+    P = rows * 128
+    g = jax.random.normal(jax.random.PRNGKey(rows), (P,))
+    args = (g, KEY_XI, KEY_P) + _noise_batch_case(n)
+    ref_out = jax.jit(fref.noise_batch_ref)(*args)
+    pal = noise_batch_pallas(*args, block_rows=block_rows,
+                             strip_rows=strip_rows, interpret=True)
+    eight = noise_batch_pallas(*args, block_rows=8, strip_rows=8,
+                               interpret=True)
+    np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref_out))
+    np.testing.assert_array_equal(np.asarray(pal), np.asarray(eight))
 
 
 # ---------------------------------------------------------------------------

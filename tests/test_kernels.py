@@ -7,7 +7,8 @@ pytest.importorskip("hypothesis")  # property tests need it; collection must not
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.dp_clip import ref as dref
-from repro.kernels.dp_clip.dp_clip import clip_accumulate, per_example_sumsq
+from repro.kernels.dp_clip.dp_clip import (PACKED_BLOCK_D, accumulate_blocks,
+                                          clip_accumulate, per_example_sumsq)
 from repro.kernels.flash_attention import ref as fref
 from repro.kernels.flash_attention.blocked import flash_attention_xla
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
@@ -115,6 +116,36 @@ def test_dp_clip_sweep(shape, dtype):
     ca_ref = dref.clip_accumulate_ref(g, s)
     np.testing.assert_allclose(np.asarray(ca_pal), np.asarray(ca_ref),
                                rtol=3e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("B,D,block_d", [
+    (2, 1024 * 11, 4096),           # 3 blocks, the last of 3,072 lanes
+    (4, 1024 * 5, 2048),            # 3 blocks, the last of 1,024 lanes
+    (2, PACKED_BLOCK_D + 3072, None),  # the default wide block, ragged
+    (4, 1024 * 7, None),            # the default: one block of every lane
+])
+def test_clip_accumulate_packed_wide_block_ragged(B, D, block_d):
+    """A packed (n_silos, P) stack in lane-wide blocks of all B rows, the
+    last block ragged: the sum equals the oracle's."""
+    g = jax.random.normal(jax.random.PRNGKey(B + D), (B, D))
+    s = jax.random.uniform(jax.random.PRNGKey(1), (B,))
+    got = clip_accumulate(g, s, block_d=block_d, interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(dref.clip_accumulate_ref(g, s)),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("B,D,blocks", [
+    (2, 231947264, (2, PACKED_BLOCK_D)),  # the 2-silo packed stack
+    (8, 1024 * 3, (8, 1024 * 3)),         # 8 silos, a short buffer
+    (8, 300, (8, 300)),                   # per-leaf: one small leaf
+    (4, 1536, (4, 512)),                  # per-leaf: D not a multiple of 1024
+    (16, 2048, (8, 512)),                 # per-leaf: more than 8 examples
+])
+def test_clip_accumulate_blocks_by_shape(B, D, blocks):
+    """Only a packed stack (B <= 8, D a multiple of 1024) takes the wide
+    block; a per-leaf (B, D) operand keeps the (min(8, B), 512) blocks."""
+    assert accumulate_blocks(B, D) == blocks
 
 
 # ---------------------------------------------------------------------------

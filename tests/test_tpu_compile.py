@@ -13,7 +13,9 @@ The topology is described inside a fixture and never at import: only one
 process may load the TPU library at a time.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,3 +114,29 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, packed_size):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _large_moves(hlo: str, size: int) -> list[str]:
+    """The copies and reshapes in an HLO text whose result holds at least
+    ``size`` elements (a bitcast moves nothing and is not listed)."""
+    moves = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (copy|reshape)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                >= size:
+            moves.append(line.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("kernel", ["noise_batch", "clip_accumulate"])
+def test_packed_kernel_streams_buffer_in_place(kernel, one_chip, packed_size):
+    """At the real packed size the DP kernels' views of the buffer are free:
+    one custom call, and no copy or relayout reshape of a P-sized array
+    (the (P // 128, 128) view and the (1, P) -> (P,) hand-off lower to
+    bitcasts)."""
+    fn, shapes = _cases(packed_size)[kernel]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert _large_moves(hlo, packed_size) == []
