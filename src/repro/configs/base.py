@@ -45,9 +45,16 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_conv: int = 4
     mamba_headdim: int = 64
-    # hybrid (zamba2): one *shared* attention block applied every `attn_every`
-    # mamba layers
-    attn_every: int = 0
+    mamba_ngroups: int = 1  # B/C groups; head h reads group h // (heads / groups)
+    mamba_chunk: int = 64  # SSD chunk length
+    # hybrid (zamba2): the layers at `hybrid_layer_ids` run one of
+    # `n_mem_blocks` parameter-shared attention+MLP blocks (alternately) over
+    # concat(residual, embedding), with a per-invocation rank-`adapter_rank`
+    # MLP adapter and output projection, before their mamba mixer
+    hybrid_layer_ids: tuple[int, ...] = ()
+    n_mem_blocks: int = 2
+    adapter_rank: int = 0
+    norm_eps: float = 1e-6  # RMSNorm eps
     # modality frontend stub: inputs arrive as precomputed embeddings
     frontend: str = "none"  # none | patches | frames
     # Megatron-style sequence parallelism: residuals between blocks are
@@ -94,22 +101,6 @@ def _per_layer_params(cfg: ModelConfig) -> tuple[int, int]:
     if cfg.qkv_bias:
         attn += cfg.attn_inner + 2 * cfg.kv_inner
     norms = 2 * d
-    if cfg.family == "hybrid":
-        # mamba2 layer params
-        d_in = cfg.mamba_expand * d
-        nh = d_in // cfg.mamba_headdim
-        mamba = (
-            d * (2 * d_in + 2 * cfg.ssm_state + nh)  # in_proj -> x,z,B,C,dt
-            + cfg.mamba_conv * (d_in + 2 * cfg.ssm_state)  # conv1d
-            + nh * 2  # A_log, D
-            + d_in * d  # out_proj
-            + 2 * d
-        )
-        # shared attention block amortized over attn_every layers
-        shared_ffn = 3 * d * cfg.d_ff
-        shared = attn + shared_ffn + norms
-        p = mamba + shared // max(cfg.attn_every, 1) if cfg.attn_every else mamba
-        return p, p
     # FFN params
     if cfg.is_moe():
         ffn_tot = cfg.n_experts * 3 * d * cfg.d_ff + d * cfg.n_experts  # + router
@@ -119,7 +110,32 @@ def _per_layer_params(cfg: ModelConfig) -> tuple[int, int]:
     return attn + ffn_tot + norms, attn + ffn_act + norms
 
 
+def _hybrid_params(cfg: ModelConfig) -> int:
+    """Zamba2: every layer's Mamba2 mixer and norm, the shared blocks once
+    each, one adapter pair and output projection per hybrid layer."""
+    d, N, G = cfg.d_model, cfg.ssm_state, cfg.mamba_ngroups
+    d_in = cfg.mamba_expand * d
+    nh = d_in // cfg.mamba_headdim
+    conv_dim = d_in + 2 * G * N
+    mamba = (d * (d_in + conv_dim + nh)         # in_proj -> z, xBC, dt
+             + (cfg.mamba_conv + 1) * conv_dim   # depthwise conv + bias
+             + 3 * nh                            # A_log, D, dt_bias
+             + d_in                              # gated group norm
+             + d_in * d                          # out_proj
+             + d)                                # the layer's input norm
+    a = 2 * d
+    shared = (a * cfg.attn_inner + 2 * a * cfg.kv_inner + cfg.attn_inner * d
+              + 3 * d * cfg.d_ff + a + d)
+    per_call = d * cfg.adapter_rank + cfg.adapter_rank * 2 * cfg.d_ff + d * d
+    emb = cfg.vocab_size * d
+    head = 0 if cfg.tie_embeddings else emb
+    return (cfg.n_layers * mamba + cfg.n_mem_blocks * shared
+            + len(cfg.hybrid_layer_ids) * per_call + emb + head + d)
+
+
 def param_count(cfg: ModelConfig) -> int:
+    if cfg.family == "hybrid":
+        return _hybrid_params(cfg)
     per, _ = _per_layer_params(cfg)
     emb = cfg.vocab_size * cfg.d_model
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
@@ -127,6 +143,8 @@ def param_count(cfg: ModelConfig) -> int:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
+    if cfg.family == "hybrid":
+        return _hybrid_params(cfg)
     _, act = _per_layer_params(cfg)
     emb = cfg.vocab_size * cfg.d_model
     head = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
@@ -269,6 +287,9 @@ def reduce_for_smoke(cfg: ModelConfig, **overrides) -> ModelConfig:
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_state=16, rwkv_head_size=16, mamba_headdim=16)
     if cfg.family == "hybrid":
-        kw.update(attn_every=2)
+        # the published pattern in small: mamba and hybrid layers in order,
+        # both shared blocks, 2 groups, adapters, attention over the concat
+        kw.update(n_layers=6, hybrid_layer_ids=(2, 4), d_head=32,
+                  adapter_rank=8, mamba_chunk=16)
     kw.update(overrides)
     return dataclasses.replace(cfg, **kw)

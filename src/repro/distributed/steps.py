@@ -23,9 +23,9 @@ optimizer. Every tier runs the per-silo forward and backward pass under
 ``jax.named_scope("train.fwd_bwd")`` and the update under
 ``"train.optimizer"``, beside the engine's ``dp.*`` scopes, so a profile
 splits the step's device time between the model, the optimizer and the DP
-engine. Every step takes an ``active: (n_silos,) bool`` participation set
-(elastic silo membership — see runtime/elastic.py); ``None`` means all silos
-contribute.
+engine; the barrier tier's packed psum runs under ``dp.psum``. Every step
+takes an ``active: (n_silos,) bool`` participation set (elastic silo
+membership — see runtime/elastic.py); ``None`` means all silos contribute.
 """
 from __future__ import annotations
 
@@ -264,7 +264,9 @@ def _barrier_grads(model: Model, priv: PrivacyConfig, mesh_cfg: MeshConfig,
                         prev_active=prev_active if has_prev_active else None)
         contrib = pipe.silo_contribution(g, idx, scale, active, keys_t, ns,
                                          clip_bound)
-        agg = pipe.finalize(jax.lax.psum(contrib, silo_axes))
+        with jax.named_scope("dp.psum"):
+            summed = jax.lax.psum(contrib, silo_axes)
+        agg = pipe.finalize(summed)
         gate = active[idx].astype(jnp.float32)
         loss_mean = jax.lax.psum(loss * gate, silo_axes) / \
             pipe.active_count(active)

@@ -173,6 +173,40 @@ def paged_kv_write(k_pages, v_pages, k, v, tables, q_start, n_valid):
     return k_pages, v_pages
 
 
+def paged_kv_write_pages(k_pages, v_pages, k, v, tables, q_start, n_valid):
+    """As ``paged_kv_write``, by whole pages: gather the pages the chunk
+    touches, merge the chunk's valid rows into them, and scatter the pages
+    back along the pool's page axis only. A scatter that indexes the page
+    and the in-page offset makes XLA relayout the whole (N, Hkv, P, Dh) pool
+    around it (a copy of every page, each call); this one moves the touched
+    pages alone. Zamba2-7B's 12-layer serving step over 8 slots of 4,096
+    tokens needs 2.1 GB of temporaries with this write and 6.6 GB with
+    ``paged_kv_write``, which then overflows a TPU v5e's 16 GB by 1.6 GB
+    (XLA's account for a described v5e). A page holding no valid row is
+    dropped (sent to id N), so no page is written twice in one call."""
+    N, H, P, D = k_pages.shape
+    B, C = k.shape[0], k.shape[1]
+    nP = 1 if C == 1 else -(-C // P) + 1        # pages a chunk can touch
+    j = q_start[:, None] // P + jnp.arange(nP)[None, :]        # (B, nP)
+    page = jnp.take_along_axis(tables, jnp.clip(j, 0, tables.shape[1] - 1),
+                               axis=1)
+    pos = j[:, :, None] * P + jnp.arange(P)[None, None, :]     # (B, nP, P)
+    c = pos - q_start[:, None, None]
+    new = (c >= 0) & (c < n_valid[:, None, None])
+    page = jnp.where(new.any(-1), page, N)                     # OOB -> dropped
+    c = jnp.clip(c, 0, C - 1)
+
+    def merge(pages, rows):
+        old = pages.at[page].get(mode="fill", fill_value=0)    # (B,nP,H,P,D)
+        fresh = jax.vmap(lambda r, i: r[i])(rows, c.reshape(B, -1))
+        fresh = fresh.reshape(B, nP, P, H, D).transpose(0, 1, 3, 2, 4)
+        out = jnp.where(new[:, :, None, :, None],
+                        fresh.astype(pages.dtype), old)
+        return pages.at[page].set(out, mode="drop")
+
+    return merge(k_pages, k), merge(v_pages, v)
+
+
 def attn_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
                      tables, q_start, n_valid):
     """Paged-cache attention step: write the chunk's K/V through the block

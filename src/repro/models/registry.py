@@ -26,13 +26,17 @@ class Model:
     init_cache: Callable[..., Any]
     prefill: Callable[..., Any]
     decode_step: Callable[..., Any]
-    # paged serving surface (transformer families only; None elsewhere):
-    # init_paged_cache(n_pages, page_size) -> pool pytree;
+    # paged serving surface (transformer and hybrid families; None elsewhere):
+    # init_paged_cache(n_pages, page_size, n_slots=None) -> pool pytree;
     # paged_step(params, tokens, pool, tables, q_start, n_valid,
     #            logits_mode="last") -> (logits, pool) — one function for
     #   prefill chunks, decode, and (logits_mode="all") speculative verify
     init_paged_cache: Optional[Callable[..., Any]] = None
     paged_step: Optional[Callable[..., Any]] = None
+    # families whose pool also holds a recurrent state slot per sequence:
+    # reset_slot_state(pool, slot) -> pool, zeroing that slot's state on the
+    # device (None where the pool is pages only)
+    reset_slot_state: Optional[Callable[..., Any]] = None
     # dtype the paged pool/step run in — the scheduler needs it to build a
     # draft model that shares the target's page layout
     compute_dtype: Any = jnp.bfloat16
@@ -49,8 +53,9 @@ def build_model(cfg: ModelConfig, param_dtype=jnp.float32,
             init_cache=lambda batch, max_len: transformer.init_cache(cfg, batch, max_len, compute_dtype),
             prefill=lambda p, b, c: transformer.prefill(p, cfg, b, c, compute_dtype),
             decode_step=lambda p, b, c: transformer.decode_step(p, cfg, b, c, compute_dtype),
-            init_paged_cache=lambda n_pages, page_size: transformer.init_paged_cache(
-                cfg, n_pages, page_size, compute_dtype),
+            init_paged_cache=lambda n_pages, page_size, n_slots=None: \
+                transformer.init_paged_cache(cfg, n_pages, page_size,
+                                             compute_dtype),
             paged_step=lambda p, toks, pool, tables, q_start, n_valid, \
                 logits_mode="last":
                 transformer.forward_paged(p, cfg, toks, pool, tables,
@@ -75,5 +80,14 @@ def build_model(cfg: ModelConfig, param_dtype=jnp.float32,
             init_cache=lambda batch, max_len: hybrid.init_state(cfg, batch, max_len, compute_dtype),
             prefill=lambda p, b, c: hybrid.decode_step(p, cfg, b, c, compute_dtype),
             decode_step=lambda p, b, c: hybrid.decode_step(p, cfg, b, c, compute_dtype),
+            init_paged_cache=lambda n_pages, page_size, n_slots: \
+                hybrid.init_paged_cache(cfg, n_pages, page_size, n_slots,
+                                        compute_dtype),
+            paged_step=lambda p, toks, pool, tables, q_start, n_valid, \
+                logits_mode="last":
+                hybrid.forward_paged(p, cfg, toks, pool, tables, q_start,
+                                     n_valid, compute_dtype, logits_mode),
+            reset_slot_state=hybrid.reset_slot_state,
+            compute_dtype=compute_dtype,
         )
     raise ValueError(f"unknown family {cfg.family!r}")

@@ -41,10 +41,10 @@ def layer_init(key, cfg: ModelConfig, dtype=jnp.float32):
 
 
 def layer_apply(p, x, cfg: ModelConfig, positions, cache_entry=None, use_flash=False):
-    h, new_cache = attn.attn_apply(p["attn"], rmsnorm(p["ln1"], x), cfg, positions,
-                                   cache_entry, use_flash)
+    h, new_cache = attn.attn_apply(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                                   cfg, positions, cache_entry, use_flash)
     x = x + h
-    y = rmsnorm(p["ln2"], x)
+    y = rmsnorm(p["ln2"], x, cfg.norm_eps)
     if cfg.is_moe():
         f, aux = moe_mod.moe_apply(p["moe"], y, cfg, cfg.moe_capacity_factor)
     else:
@@ -107,7 +107,7 @@ def forward(params, cfg: ModelConfig, batch: dict, cache=None, use_flash=False,
             return h, (nc, aux)
         x, (new_cache, auxs) = jax.lax.scan(body_c, x, (params["layers"], cache))
 
-    x = rmsnorm(params["ln_f"], x)
+    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     if logits_mode == "hidden":
         return x, new_cache, jnp.sum(auxs)
     if logits_mode == "last":
@@ -145,13 +145,13 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
 def layer_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
                       tables, q_start, n_valid):
     with jax.named_scope("paged.qkv"):
-        y = rmsnorm(p["ln1"], x)
+        y = rmsnorm(p["ln1"], x, cfg.norm_eps)
     h, k_pages, v_pages = attn.attn_apply_paged(
         p["attn"], y, cfg, positions, k_pages, v_pages, tables, q_start,
         n_valid)
     x = x + h
     with jax.named_scope("paged.mlp"):
-        y = rmsnorm(p["ln2"], x)
+        y = rmsnorm(p["ln2"], x, cfg.norm_eps)
         if cfg.is_moe():
             f, _ = moe_mod.moe_apply(p["moe"], y, cfg, cfg.moe_capacity_factor)
         else:
@@ -197,7 +197,7 @@ def forward_paged(params, cfg: ModelConfig, tokens, pages: dict, tables,
         body, x, (params["layers"], pages["k_pages"], pages["v_pages"]))
     pages = {"k_pages": k_pages, "v_pages": v_pages}
     with jax.named_scope("paged.head"):
-        x = rmsnorm(params["ln_f"], x)
+        x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
         head = params.get("lm_head", None)
         if head is None:
             head = params["embed"].T

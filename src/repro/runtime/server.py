@@ -82,6 +82,9 @@ class ServerStats:
     # after the call / table pages the kernel's grid visits
     pages_live: int = 0
     pages_walked: int = 0
+    # slots whose recurrent state was zeroed on admission (families whose
+    # pool holds one)
+    state_resets: int = 0
 
     @property
     def utilization(self) -> float:

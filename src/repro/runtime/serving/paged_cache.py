@@ -10,6 +10,15 @@ previous tenant's K/V — recycling is safe by construction, not by
 cache-lifetime discipline (the serving analogue of the paper's R2 state
 isolation).
 
+For a family whose pool also holds recurrent state (the hybrid family: one
+Mamba2 state slot per sequence per layer beside the attention pages), the
+contract covers the state too: ``alloc`` zeroes the slot's state on the
+device (``Model.reset_slot_state``) in the same reset that zeroes its fresh
+pages, before the slot is published, so a recycled slot's first prefill
+starts from zero, never from the previous tenant's state. Prefix sharing and
+speculative rollback would need state snapshots and are refused for such a
+family (``ContinuousServer``).
+
 Prefix sharing rides on two additions, both scoped so the R2 analogue
 survives intact:
 
@@ -69,7 +78,10 @@ class PagePool:
                 f"path; continuous batching needs a transformer-family model")
         self.page_size = page_size
         self.n_pages = n_pages
-        self.pages = model.init_paged_cache(n_pages, page_size)
+        self.pages = model.init_paged_cache(n_pages, page_size,
+                                            n_slots=n_slots)
+        # zeroes a slot's recurrent state; None when the pool is pages only
+        self._reset_state = model.reset_slot_state
         # draft pool: same page ids, draft-sized K/V. Shared-prefix pages are
         # populated for BOTH pools during the original request's prefill, so
         # a sharer admitted later finds its draft cache warm too.
@@ -93,14 +105,21 @@ class PagePool:
     def free_pages(self) -> int:
         return len(self.free)
 
+    @property
+    def has_state(self) -> bool:
+        """True when the pool holds a recurrent state slot per sequence."""
+        return self._reset_state is not None
+
     # ------------------------------------------------------------- reset glue
-    def _reset_rows(self, row: np.ndarray) -> None:
+    def _reset_rows(self, row: np.ndarray, slot: int) -> None:
         """Zero ``row``'s pages in-kernel in the target pool and (when
-        present) the draft pool. Pools are consumed and rebound."""
-        self.pages = dict(zip(
-            ("k_pages", "v_pages"),
-            paged_ops.paged_reset(self.pages["k_pages"],
-                                  self.pages["v_pages"], row)))
+        present) the draft pool, and ``slot``'s recurrent state where the
+        pool holds one. Pools are consumed and rebound."""
+        k, v = paged_ops.paged_reset(self.pages["k_pages"],
+                                     self.pages["v_pages"], row)
+        self.pages = {**self.pages, "k_pages": k, "v_pages": v}
+        if self._reset_state is not None:
+            self.pages = self._reset_state(self.pages, slot)
         if self.draft_pages is not None:
             self.draft_pages = dict(zip(
                 ("k_pages", "v_pages"),
@@ -138,7 +157,7 @@ class PagePool:
         # compiled reset graph serves every allocation shape.
         reset_row = np.full((width,), fresh[0], np.int32)
         reset_row[:fresh_n] = fresh
-        self._reset_rows(reset_row)
+        self._reset_rows(reset_row, slot)
         for p in shared:
             self.refcount[p] += 1
         self.refcount[fresh] = 1
@@ -247,10 +266,9 @@ class PagePool:
         assert start >= len(self._shared[slot]) * self.page_size
         assert end <= len(self._owned[slot]) * self.page_size
         row = self.tables[slot]
-        self.pages = dict(zip(
-            ("k_pages", "v_pages"),
-            paged_ops.paged_rollback(self.pages["k_pages"],
-                                     self.pages["v_pages"], row, start, end)))
+        k, v = paged_ops.paged_rollback(self.pages["k_pages"],
+                                        self.pages["v_pages"], row, start, end)
+        self.pages = {**self.pages, "k_pages": k, "v_pages": v}
         if self.draft_pages is not None:
             self.draft_pages = dict(zip(
                 ("k_pages", "v_pages"),

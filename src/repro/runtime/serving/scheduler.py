@@ -51,7 +51,8 @@ unless a profiler is running: ``serve.tick`` around ``step``; inside it
 jitted call, argument transfer included), ``serve.fetch`` (logits to the
 host, waiting for the device) and ``serve.sample`` (argmax, append, finish).
 A ``serve.dispatch`` span carries its call's increments of the
-``ServerStats`` row and page counters as arguments, and a
+``ServerStats`` row and page counters as arguments, ``serve.admit`` its
+increment of ``state_resets``, and a
 ``serve.first_token`` mark carries each request's admission wait and
 prefill time (ms).
 
@@ -144,6 +145,14 @@ class ContinuousServer:
         self.n_slots = max_batch
         self.max_len = max_len
         self.prefill_chunk = prefill_chunk
+        if model.reset_slot_state is not None and (prefix_sharing
+                                                   or speculative):
+            raise ValueError(
+                f"{model.cfg.name} ({model.cfg.family}) keeps a recurrent "
+                f"state per sequence: prefix sharing and speculative "
+                f"rollback need snapshots of it, which the pool does not "
+                f"take; serve it with prefix_sharing=False, "
+                f"speculative=False")
         self.prefix_sharing = prefix_sharing
         self.speculative = speculative
         self.spec_k = spec_k
@@ -280,6 +289,7 @@ class ContinuousServer:
                     self._deficit[t] = (self._deficit.get(t, 0.0)
                                         + self._weight(t) / W)
             self._deficit[t_star] = self._deficit.get(t_star, 0.0) - 1.0
+            self.stats.state_resets += self.pool.has_state
             if not self.queues[t_star]:
                 del self.queues[t_star]
                 self._deficit.pop(t_star, None)
@@ -544,8 +554,11 @@ class ContinuousServer:
         with TraceAnnotation("serve.tick"):
             self.clock += 1
             before = self.stats.useful_tokens
-            with TraceAnnotation("serve.admit"):
+            resets = self.stats.state_resets
+            with TraceAnnotation("serve.admit") as span:
                 self._admit()
+                span.set_metadata(
+                    state_resets=self.stats.state_resets - resets)
             with TraceAnnotation("serve.decode"):
                 if self.speculative:
                     self._run_spec_decode()

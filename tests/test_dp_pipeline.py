@@ -358,6 +358,12 @@ for active_np in (np.ones(N, bool), np.array([True, False, True, True])):
     print("active", active_np.tolist(), "barrier-vs-fused max err:", err)
     assert err < 1e-3, err
     assert abs(float(loss) - float(loss_f)) < 1e-5
+# the packed psum, the tier's one collective, is scoped for a profile
+with jax.set_mesh(mesh):
+    text = jax.jit(lambda p, b: steps_mod._barrier_grads(
+        model, priv, mesh_cfg, p, b, keys, ns, jnp.float32(1.0),
+        keys.key_clip, mesh)).lower(params, batch).compile().as_text()
+assert any("all-reduce" in l and "/dp.psum/" in l for l in text.splitlines())
 print("OK")
 """
 

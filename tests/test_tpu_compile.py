@@ -140,3 +140,41 @@ def test_packed_kernel_streams_buffer_in_place(kernel, one_chip, packed_size):
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     assert _large_moves(hlo, packed_size) == []
+
+
+@pytest.mark.parametrize("chunk", [256, 1])
+def test_hybrid_paged_step_compiles_for_v5e(chunk, one_chip, monkeypatch):
+    """The hybrid family's serving step at Zamba2-7B's published widths, one
+    mamba and one hybrid layer, 8 slots of 4,096 tokens: a 256-row prefill
+    chunk runs the SSD scan as the Pallas kernel (2 groups by its index map)
+    and the shared block's attention as the paged kernel, both
+    ``tpu_custom_call``s; decode runs the paged kernel and the one-step state
+    update. The registry's resolutions show no fallback from Pallas."""
+    from repro.configs.zamba2_7b import CONFIG as ZAMBA2
+    from repro.kernels import dispatch
+    from repro.kernels.mamba2 import ops as ssd_ops
+    from repro.kernels.paged_attention import ops as paged_ops
+
+    for mod in (dispatch, ssd_ops, paged_ops):  # the host is not the chip
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(ZAMBA2, n_layers=2, hybrid_layer_ids=(1,))
+    model = build_model(cfg, compute_dtype=jnp.float32)
+    slots, max_len, page = 8, 4096, 16
+    sds = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(model.init,
+                                              jax.random.PRNGKey(0)))
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.init_paged_cache(slots * max_len // page, page,
+                                       n_slots=slots)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    with dispatch.REGISTRY.record() as used:
+        hlo = jax.jit(model.paged_step, donate_argnums=(2,)).lower(
+            params, i32(slots, chunk), pool, i32(slots, max_len // page),
+            i32(slots), i32(slots)).compile().as_text()
+    calls = [l for l in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert any("paged_attention" in l for l in calls)
+    assert used["paged_attention"] == {"pallas"}
+    if chunk > 1:
+        assert any("ssd" in l for l in calls), calls
+        assert used["mamba2_ssd"] == {"pallas"}
