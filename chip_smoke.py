@@ -231,12 +231,13 @@ def probe_paged_attention(jax, srv, seed, check):
     v = srv.pool.pages["v_pages"][0]
     tables = jnp.asarray(srv.pool.tables)
     q_start = jnp.asarray(srv.pool.lengths)
+    n_valid = jnp.asarray([int(s is not None) for s in srv.slots], jnp.int32)
     cfg = srv.model.cfg
     q = jax.random.normal(jax.random.PRNGKey(seed + 7),
-                          (srv.n_slots, 1, cfg.n_heads, cfg.d_head),
+                          (srv.n_slots, 1, cfg.n_heads, k.shape[-1]),
                           jnp.float32)
-    got = paged_attention_pallas(q, k, v, tables, q_start)
-    want = paged_attention_oracle(q, k, v, tables, q_start)
+    got = paged_attention_pallas(q, k, v, tables, q_start, n_valid)
+    want = paged_attention_oracle(q, k, v, tables, q_start, n_valid)
     err = float(jnp.max(jnp.abs(got - want)))
     scale = float(jnp.max(jnp.abs(v)))
     say(f"serve: paged attention pallas vs jnp on the live pool, slots at "

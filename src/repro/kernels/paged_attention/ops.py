@@ -30,33 +30,40 @@ ROLLBACK_KERNEL = "paged_rollback"
 @kernel_variant(KERNEL, "pallas", priority=100,
                 auto_predicate=lambda ctx: ctx["on_tpu"],
                 doc="block-table Pallas kernel (interpret mode off-TPU)")
-def _pallas(q, k_pages, v_pages, tables, q_start):
+def _pallas(q, k_pages, v_pages, tables, q_start, n_valid):
     return paged_attention_pallas(q, k_pages, v_pages, tables, q_start,
-                                  interpret=not on_tpu())
+                                  n_valid, interpret=not on_tpu())
 
 
 @kernel_variant(KERNEL, "gather", priority=50,
                 doc="vectorized gather + masked softmax (compiled CPU path)")
-def _gather(q, k_pages, v_pages, tables, q_start):
-    return ref.paged_attention_gather(q, k_pages, v_pages, tables, q_start)
+def _gather(q, k_pages, v_pages, tables, q_start, n_valid):
+    return ref.paged_attention_gather(q, k_pages, v_pages, tables, q_start,
+                                      n_valid)
 
 
 @kernel_variant(KERNEL, "jnp", priority=10,
                 auto_predicate=lambda ctx: False,
                 doc="unrolled bit-exact oracle (explicit request only)")
-def _jnp(q, k_pages, v_pages, tables, q_start):
-    return ref.paged_attention_oracle(q, k_pages, v_pages, tables, q_start)
+def _jnp(q, k_pages, v_pages, tables, q_start, n_valid):
+    return ref.paged_attention_oracle(q, k_pages, v_pages, tables, q_start,
+                                      n_valid)
 
 
-def paged_attention(q, k_pages, v_pages, tables, q_start, impl: str = "auto"):
+def paged_attention(q, k_pages, v_pages, tables, q_start, n_valid,
+                    impl: str = "auto"):
     """Attention for C new tokens per slot against the slot's paged KV.
 
     q: (B, C, Hq, D); k_pages/v_pages: (N, Hkv, P, D); tables: (B, nP) i32;
     q_start: (B,) i32 tokens already cached (q row c reads positions
-    <= q_start + c). Returns fp32 (B, C, Hq, D)."""
+    <= q_start + c); n_valid: (B,) i32 new tokens of the slot in this call
+    (its K/V already written). Only the slot's live pages, the first
+    ceil((q_start + n_valid) / P) of its row, are read; a slot with
+    ``n_valid == 0`` reads none and its rows come out as zeros. Returns fp32
+    (B, C, Hq, D); rows past ``n_valid`` are finite and meaningless."""
     return REGISTRY.dispatch(KERNEL, impl,
                              {"C": q.shape[1], "P": k_pages.shape[2]},
-                             q, k_pages, v_pages, tables, q_start)
+                             q, k_pages, v_pages, tables, q_start, n_valid)
 
 
 @kernel_variant(RESET_KERNEL, "pallas", priority=100,

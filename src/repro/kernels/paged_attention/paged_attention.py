@@ -5,31 +5,44 @@ ids — instead of a contiguous per-request cache, so a finished request's
 pages can be recycled into any other slot. Isolation is enforced in the
 kernel, twice over:
 
-* the attention kernel can only touch pages named in the slot's own table
-  row (the scalar-prefetch index map IS the access path — there is no
-  base+offset arithmetic that could wander into another slot's pages), and
-  the per-slot length mask clips reads to positions the slot has written;
+* the attention kernel can only touch pages named in the first live entries
+  of the slot's own table row: each copy's page id is read from that row in
+  SMEM at a column clamped to the slot's last live page, so there is no
+  base+offset arithmetic that could wander into another slot's pages, and
+  the table entries past the live ones (padding, stale ids) are never
+  read; the per-slot length mask clips reads to positions the slot has
+  written;
 * ``paged_reset`` zeroes a slot's pages *in-kernel* on admission
   (``input_output_aliases`` makes it an in-place write on TPU), so a freshly
   admitted request's attention output is bit-equal to a fresh-cache run by
   construction — whatever a previous tenant left in those pages is gone
   before the first read.
 
-Bit-identity contract: ``_page_step`` and ``_mask`` below are shared
-*verbatim* by the Pallas kernel body and the jnp oracle (``ref.py``), so
-both trace to the same XLA ops and the parity tests can assert bitwise
-equality, not just allclose (XLA contracts mul+add chains into FMA under
-jit; two textually different formulations of the same recurrence diverge
-by 1 ulp).
+The walk: slot ``b`` has ``live_pages`` = ceil((q_start + n_valid) / P) live
+pages (none when ``n_valid == 0``). A grid step takes one slot and a group
+of ``block_heads`` kv heads and loops over the slot's live pages only, in
+blocks of ``block_pages`` pages (at least 128 tokens where the table is that
+long), copying each block's pages by id from the pool in HBM into a
+double-buffered VMEM block while the previous block computes. Both sizes
+come from the call's shapes; a slot with no live pages copies nothing and
+writes zeros.
+
+Bit-identity contract: ``_block_step`` and ``_mask`` below are shared
+*verbatim* by the Pallas kernel body and the jnp oracle (``ref.py``), which
+also walks the same blocks of the same page ids, so both trace to the same
+XLA ops and the parity tests can assert bitwise equality, not just allclose
+(XLA contracts mul+add chains into FMA under jit; two textually different
+formulations of the same recurrence diverge by 1 ulp).
 
 Layouts:
   q                (B, C, Hq, D)   — C = chunk of new tokens per slot
   k_pages/v_pages  (N, Hkv, P, D)  — one layer's pool: N pages of P tokens,
-                                     head-major so a kernel block is one
-                                     head's (P, D) page (TPU (8, 128) tiling)
+                                     head-major so one page's kv heads are
+                                     contiguous (P, D) tiles (TPU (8, 128))
   tables           (B, nP) int32   — per-slot physical page ids
   q_start          (B,)    int32   — tokens already in the slot's cache
                                      (q row c sits at position q_start + c)
+  n_valid          (B,)    int32   — new tokens of the slot in this call
 """
 from __future__ import annotations
 
@@ -41,11 +54,61 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_TOKENS = 128        # kv tokens a block aims for: a full lane width
+ROWS_PER_STEP = 256       # query rows (heads x folded rows) a step aims for
+VMEM_BUDGET = 16 << 20    # what one grid step's blocks may take in VMEM
+VMEM_LIMIT = 32 << 20     # the kernel's scoped VMEM (a v5e core has 128 MiB)
 
 
-def _page_step(q, k, v, m, l, acc, mask, sm_scale):
-    """One page of the online-softmax recurrence — shared verbatim by the
-    Pallas kernel body and the jnp oracle, so both trace to the same ops."""
+def _vmem_bytes(k, heads, rows, d_head, itemsize, page_size):
+    """VMEM one grid step holds: the double-buffered K and V blocks, the q
+    and out blocks (double-buffered by the pipeline), the accumulators, and
+    a few (rows, k*P) score tiles of one head."""
+    rows = max(rows, 2)
+    kv = 2 * 2 * k * page_size * heads * d_head * itemsize
+    qo = 4 * heads * rows * d_head * 4
+    acc = heads * rows * (d_head + 2) * 4
+    return kv + qo + acc + 4 * rows * k * page_size * 4
+
+
+def block_pages(rows, d_head, itemsize, page_size, n_pages):
+    """Pages per block of the walk, from the call's shapes: enough for
+    ``BLOCK_TOKENS`` kv tokens, no more than the table holds, fewer while one
+    head's step would not fit ``VMEM_BUDGET``. ``rows``: query rows per kv
+    head (``Hq // Hkv * C``). The serving scheduler counts the pages the
+    walk visits with this same function."""
+    k = max(1, min(n_pages, -(-BLOCK_TOKENS // page_size)))
+    while k > 1 and _vmem_bytes(k, 1, rows, d_head, itemsize,
+                                page_size) > VMEM_BUDGET:
+        k -= 1
+    return k
+
+
+def block_heads(n_kv_heads, rows, d_head, itemsize, page_size, k):
+    """Kv heads per grid step: the most (a divisor of ``n_kv_heads``) whose
+    query rows stay within ``ROWS_PER_STEP`` and whose blocks fit
+    ``VMEM_BUDGET`` — all of a page's heads for MHA decode, one for long
+    GQA chunks. A page's heads are contiguous in the pool, so one copy per
+    page still moves them all."""
+    h = n_kv_heads
+    while h > 1 and (n_kv_heads % h or h * max(rows, 2) > ROWS_PER_STEP
+                     or _vmem_bytes(k, h, rows, d_head, itemsize,
+                                    page_size) > VMEM_BUDGET):
+        h -= 1
+    return h
+
+
+def live_pages(q_start, n_valid, page_size, n_pages):
+    """Table pages holding each slot's tokens after the call: ceil((q_start
+    + n_valid) / P), clipped to the table; 0 for a slot with no new rows."""
+    n = -(-(q_start + n_valid) // page_size)
+    return jnp.where(n_valid > 0, jnp.clip(n, 0, n_pages), 0)
+
+
+def _block_step(q, k, v, m, l, acc, mask, sm_scale):
+    """One block of the online-softmax recurrence — shared verbatim by the
+    Pallas kernel body and the jnp oracle, so both trace to the same ops.
+    q: (rows, D); k, v: (k*P, D); mask: (rows, k*P)."""
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
     s = jnp.where(mask, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -56,15 +119,24 @@ def _page_step(q, k, v, m, l, acc, mask, sm_scale):
     return m_new, l_new, acc_new
 
 
-def _mask(q_start_b, j, page_size, chunk, GC):
-    """Causal+length mask for page ``j`` against the folded (G*C, P) score
-    tile: row r is query chunk-token ``r mod chunk`` at absolute position
-    ``q_start + r mod chunk``; kv column col is absolute ``j*P + col``."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (GC, page_size), 0)
+def _mask(q_start_b, j, block, chunk, GC):
+    """Causal+length mask for block ``j`` (``block`` kv tokens) against the
+    folded (G*C, block) score tile: row r is query chunk-token ``r mod
+    chunk`` at absolute position ``q_start + r mod chunk``; kv column col is
+    absolute ``j*block + col``."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (GC, block), 0)
     c = jax.lax.rem(r, chunk)
-    kvpos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (GC, page_size), 1)
+    kvpos = j * block + jax.lax.broadcasted_iota(jnp.int32, (GC, block), 1)
     return kvpos <= q_start_b + c
+
+
+def block_ids(entry, j, k, live):
+    """Page ids of block ``j`` of a slot's table row: columns ``j*k ..
+    j*k+k-1``, each clamped to the last live one (``live >= 1``), so a block
+    that ends past the slot's live pages repeats its last live page — masked
+    out — instead of reading a table entry past them. ``entry(c)`` reads
+    column ``c`` of the row (the kernel's SMEM table, the oracle's array)."""
+    return [entry(jnp.minimum(j * k + i, live - 1)) for i in range(k)]
 
 
 def _fold(q, B, C, Hq, Hkv, D):
@@ -84,7 +156,7 @@ def _unfold(o, B, C, Hq, Hkv, D):
 def _fold_padded(q, B, C, Hq, Hkv, D):
     """Fold, then pad the row axis to >= 2 (duplicate the single row).
 
-    A one-row score tile makes ``_page_step``'s dots rank-1, and XLA lowers
+    A one-row score tile makes ``_block_step``'s dots rank-1, and XLA lowers
     a rank-1 contraction through a different reduction than the matrix case
     — 1-ulp divergence that breaks the bit-identity contract between the
     kernel and the oracle. Padding only triggers for MHA decode (G == 1,
@@ -97,63 +169,99 @@ def _fold_padded(q, B, C, Hq, Hkv, D):
     return qt, GC, max(GC, 2)
 
 
-def _paged_kernel(tables_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, n_pages, chunk,
-                  gc, sm_scale):
+def _paged_kernel(live_ref, tables_ref, qstart_ref, q_ref, k_hbm, v_hbm,
+                  o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
+                  page_size, block, heads, chunk, gc, sm_scale):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    h0 = pl.program_id(1) * heads
+    live = live_ref[b]
+    n_blocks = (live + block - 1) // block
+    D = k_buf.shape[-1]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def copies(j, slot):
+        ids = block_ids(lambda c: tables_ref[b, c], j, block, live)
+        return [pltpu.make_async_copy(pool.at[page, pl.ds(h0, heads)],
+                                      buf.at[slot, i], sems.at[t, slot])
+                for i, page in enumerate(ids)
+                for t, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
 
-    q = q_ref[0, 0].astype(jnp.float32)      # (gc, D) — row-padded fold
-    k = k_ref[0, 0].astype(jnp.float32)      # (P, D)
-    v = v_ref[0, 0].astype(jnp.float32)
-    mask = _mask(qstart_ref[b], j, page_size, chunk, gc)
-    m, l, acc = _page_step(q, k, v, m_scr[...], l_scr[...], acc_scr[...],
-                           mask, sm_scale)
-    m_scr[...], l_scr[...], acc_scr[...] = m, l, acc
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == n_pages - 1)
-    def _done():
-        # NEG_INF is finite, so even a fully-masked row (inactive slot,
-        # q_start < 0) yields a finite softmax — garbage the host ignores,
-        # never a NaN that could poison the shared graph
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def body(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _next():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        mask = _mask(qstart_ref[b], j, block * page_size, chunk, gc)
+        for h in range(heads):
+            q = q_ref[0, h].astype(jnp.float32)                  # (gc, D)
+            k = k_buf[slot, :, h].reshape(block * page_size, D) \
+                .astype(jnp.float32)
+            v = v_buf[slot, :, h].reshape(block * page_size, D) \
+                .astype(jnp.float32)
+            m, l, acc = _block_step(q, k, v, m_scr[h], l_scr[h], acc_scr[h],
+                                    mask, sm_scale)
+            m_scr[h], l_scr[h], acc_scr[h] = m, l, acc
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    # a slot with no live pages keeps acc = 0, l = 0: its rows come out as
+    # zeros, finite, never a NaN that could poison the shared graph
+    o_ref[0] = (acc_scr[...] /
+                jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention_pallas(q, k_pages, v_pages, tables, q_start,
+def paged_attention_pallas(q, k_pages, v_pages, tables, q_start, n_valid,
                            interpret=False):
     """Block-table paged attention; returns fp32 (B, C, Hq, D).
 
-    The grid walks (slot, kv head, page); the kv index map reads the page id
-    from the scalar-prefetched table row, so the kernel's reachable memory
-    is exactly the slot's own pages."""
+    The grid walks (slot, group of kv heads); each step loops over the
+    slot's live pages in blocks, copying each page by the id its own table
+    row names, so the kernel's reachable memory is exactly the slot's live
+    pages. On the TPU, D is a whole number of 128-lane vregs: the compiler
+    copies no narrower slice of the pool (``attention.init_paged_pool``
+    pads the heads)."""
     B, C, Hq, D = q.shape
     _, Hkv, P, _ = k_pages.shape
     nP = tables.shape[1]
     qt, GC, GCp = _fold_padded(q, B, C, Hq, Hkv, D)
-    q_spec = pl.BlockSpec((1, 1, GCp, D), lambda b, h, j, t, qs: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, P, D), lambda b, h, j, t, qs: (t[b, j], h, 0, 0))
-    o_spec = pl.BlockSpec((1, 1, GCp, D), lambda b, h, j, t, qs: (b, h, 0, 0))
+    item = k_pages.dtype.itemsize
+    k = block_pages(GC, D, item, P, nP)
+    hb = block_heads(Hkv, GC, D, item, P, k)
+    live = live_pages(q_start, n_valid, P, nP).astype(jnp.int32)
+    qo_spec = pl.BlockSpec((1, hb, GCp, D),
+                           lambda b, h, *_: (b, h, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, k, hb, P, D), k_pages.dtype)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=P, n_pages=nP, chunk=C,
-                          gc=GCp, sm_scale=1.0 / D ** 0.5),
+        functools.partial(_paged_kernel, page_size=P, block=k, heads=hb,
+                          chunk=C, gc=GCp, sm_scale=1.0 / D ** 0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, Hkv, nP),
-            in_specs=[q_spec, kv_spec, kv_spec], out_specs=o_spec,
-            scratch_shapes=[pltpu.VMEM((GCp, 1), jnp.float32),
-                            pltpu.VMEM((GCp, 1), jnp.float32),
-                            pltpu.VMEM((GCp, D), jnp.float32)],
+            num_scalar_prefetch=3, grid=(B, Hkv // hb),
+            in_specs=[qo_spec, any_spec, any_spec], out_specs=qo_spec,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((hb, GCp, 1), jnp.float32),
+                            pltpu.VMEM((hb, GCp, 1), jnp.float32),
+                            pltpu.VMEM((hb, GCp, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, GCp, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(tables, q_start, qt, k_pages, v_pages)
+    )(live, tables, q_start, qt, k_pages, v_pages)
     return _unfold(out[:, :, :GC], B, C, Hq, Hkv, D)
 
 

@@ -142,16 +142,37 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_entries: int,
 # paged KV (serving): per-slot block tables over a shared page pool
 
 
+def lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane vregs."""
+    return -(-n // 128) * 128
+
+
 def init_paged_pool(cfg: ModelConfig, n_pages: int, page_size: int,
                     n_entries: int, dtype=jnp.float32) -> dict:
     """Shared page pool for ``n_entries`` layers: requests own disjoint sets
     of pages via block tables instead of contiguous per-request caches, so a
     finished request's pages recycle into any slot (after the in-kernel
     zeroing — see kernels/paged_attention). Pages are head-major,
-    (N, Hkv, P, D) per layer, so the kernel reads one head's page as a
-    (P, D) tile."""
-    shape = (n_entries, n_pages, cfg.n_kv_heads, page_size, cfg.d_head)
+    (N, Hkv, P, D) per layer, so one page's kv heads are contiguous (P, D)
+    tiles. D is the head width zero-padded to whole 128-lane vregs: the
+    kernel copies pages by id from the pool in HBM, and the TPU compiler
+    refuses such a copy of a slice narrower than its 128-lane tiles (heads
+    of 96 then take a third more pool memory)."""
+    shape = (n_entries, n_pages, cfg.n_kv_heads, page_size, lanes(cfg.d_head))
     return {"k_pages": jnp.zeros(shape, dtype), "v_pages": jnp.zeros(shape, dtype)}
+
+
+def pad_heads(q, k, v, d_pad: int, scale: float = 1.0):
+    """Zero-pad q, k, v (..., Dh) to the pool's ``d_pad`` lanes. The kernel
+    divides scores by sqrt(d_pad), so q carries sqrt(scale * d_pad / Dh):
+    the scores come out as q.k / sqrt(Dh / scale), the model's own with
+    ``scale`` 1."""
+    Dh = q.shape[-1]
+    if d_pad == Dh and scale == 1.0:
+        return q, k, v
+    pad = ((0, 0),) * (q.ndim - 1) + ((0, d_pad - Dh),)
+    q = (q * (scale * d_pad / Dh) ** 0.5).astype(k.dtype)
+    return jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
 
 
 def paged_kv_write(k_pages, v_pages, k, v, tables, q_start, n_valid):
@@ -210,9 +231,10 @@ def paged_kv_write_pages(k_pages, v_pages, k, v, tables, q_start, n_valid):
 def attn_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
                      tables, q_start, n_valid):
     """Paged-cache attention step: write the chunk's K/V through the block
-    table, then read the whole slot back through the paged kernel. Returns
-    (out, k_pages, v_pages). The write precedes the read, so query row c at
-    position q_start + c sees itself (mask ``kvpos <= q_start + c``).
+    table, then read the slot's live pages back through the paged kernel.
+    Returns (out, k_pages, v_pages). The write precedes the read, so query
+    row c at position q_start + c sees itself (mask ``kvpos <= q_start +
+    c``). Heads are zero-padded to the pool's lane width around the kernel.
 
     Named scopes for a profile: ``paged.qkv`` (the projections),
     ``paged.kv_write`` and ``paged.attention`` (the paged kernel and the
@@ -222,11 +244,13 @@ def attn_apply_paged(p, x, cfg: ModelConfig, positions, k_pages, v_pages,
     B, S, _ = x.shape
     with jax.named_scope("paged.qkv"):
         q, k, v = _project_qkv(p, x, cfg, positions)
+        q, k, v = pad_heads(q, k, v, k_pages.shape[-1])
     with jax.named_scope("paged.kv_write"):
         k_pages, v_pages = paged_kv_write(k_pages, v_pages, k, v, tables,
                                           q_start, n_valid)
     with jax.named_scope("paged.attention"):
-        out = paged_ops.paged_attention(q, k_pages, v_pages, tables, q_start)
+        out = paged_ops.paged_attention(q, k_pages, v_pages, tables, q_start,
+                                        n_valid)[..., :cfg.d_head]
         out = out.astype(x.dtype).reshape(B, S, cfg.attn_inner)
         out = out @ p["wo"].astype(x.dtype)
     return out, k_pages, v_pages

@@ -55,7 +55,6 @@ zeroing of the slot's fresh pages.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -74,11 +73,6 @@ from repro.models.layers import (
 )
 
 SQRT2 = 2.0 ** 0.5
-
-
-def _lanes(n: int) -> int:
-    """``n`` rounded up to whole 128-lane vregs."""
-    return -(-n // 128) * 128
 
 
 def plan(cfg: ModelConfig) -> list[tuple[str, int, int]]:
@@ -314,9 +308,8 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      n_slots: int, dtype=jnp.float32) -> dict:
     _, nh, P, N, _, conv_dim = mamba2.dims(cfg)
     L = cfg.n_layers
-    pool = attn.init_paged_pool(
-        dataclasses.replace(cfg, d_head=_lanes(cfg.d_head)), n_pages,
-        page_size, len(cfg.hybrid_layer_ids), dtype)
+    pool = attn.init_paged_pool(cfg, n_pages, page_size,
+                                len(cfg.hybrid_layer_ids), dtype)
     pool["h"] = jnp.zeros((L, n_slots, nh, P, N), jnp.float32)
     pool["conv"] = jnp.zeros((L, n_slots, cfg.mamba_conv - 1, conv_dim), dtype)
     return pool
@@ -394,17 +387,16 @@ def forward_paged(params, cfg: ModelConfig, tokens, pool: dict, tables,
             u = rmsnorm(sp["ln_in"], u, cfg.norm_eps)
         with jax.named_scope("paged.qkv"):
             q, k, v = attn._project_qkv(sp["attn"], u, cfg, positions)
-            # heads zero-padded to whole vregs, as the pool holds them; the
-            # kernel divides scores by sqrt(Dp), so q carries sqrt(2 Dp / Dh)
-            q, k, v = (jnp.pad(t, ((0, 0),) * 3 + ((0, Dp - Dh),))
-                       for t in (q * (2.0 * Dp / Dh) ** 0.5, k, v))
-            q = q.astype(k.dtype)
+            # heads zero-padded to whole vregs, as the pool holds them, and
+            # the scores scaled by 1 / sqrt(Dh / 2)
+            q, k, v = attn.pad_heads(q, k, v, Dp, scale=2.0)
         with jax.named_scope("paged.kv_write"):
             kp, vp = attn.paged_kv_write_pages(kp, vp, k, v, tab, q_start,
                                                n_valid)
         with jax.named_scope("paged.attention"):
             from repro.kernels.paged_attention import ops as paged_ops
-            o = paged_ops.paged_attention(q, kp, vp, tab, q_start)[..., :Dh]
+            o = paged_ops.paged_attention(q, kp, vp, tab, q_start,
+                                          n_valid)[..., :Dh]
             a_out = o.astype(x.dtype).reshape(B, S, cfg.attn_inner) \
                 @ sp["attn"]["wo"].astype(x.dtype)
         with jax.named_scope("paged.shared"):

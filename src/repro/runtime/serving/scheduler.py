@@ -75,6 +75,7 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.paged_attention.paged_attention import block_pages
 from repro.runtime.server import Request, ServerStats
 from repro.runtime.serving.paged_cache import PagePool
 
@@ -356,19 +357,26 @@ class ContinuousServer:
         return False
 
     # ------------------------------------------------------------------ step
-    def _dispatch(self, kernel_calls, prefill: bool = False):
+    def _dispatch(self, kernel_calls, chunk: int, prefill: bool = False):
         """Count one program call and open its ``serve.dispatch`` span.
         ``kernel_calls``: the ``(lengths, n_valid)`` of each pass of the
         call through the paged kernel (one, or one per step of the draft's
-        proposal scan). Live pages cover each valid slot's cached plus new
-        tokens; walked pages are what the kernel's grid visits, the whole
-        table passed to it. ``prefill``: a chunk-program call, whose rows
-        count too."""
+        proposal scan), each ``chunk`` tokens wide. Live pages cover each
+        valid slot's cached plus new tokens; walked pages are what the
+        kernel's grid visits: each valid slot's live pages rounded up to
+        whole blocks of the kernel's ``block_pages``. ``prefill``: a
+        chunk-program call, whose rows count too."""
         P = self.pool.page_size
         rows, nP = self.pool.tables.shape
-        live = sum(int(np.sum(-(-(lengths + n_valid) // P)[n_valid > 0]))
-                   for lengths, n_valid in kernel_calls)
-        walked = len(kernel_calls) * rows * nP
+        k_pages = self.pool.pages["k_pages"]
+        cfg = self.model.cfg
+        k = block_pages(cfg.n_heads // cfg.n_kv_heads * chunk,
+                        k_pages.shape[-1], k_pages.dtype.itemsize, P, nP)
+        live = walked = 0
+        for lengths, n_valid in kernel_calls:
+            pages = -(-(lengths + n_valid) // P)[n_valid > 0]
+            live += int(np.sum(pages))
+            walked += int(np.sum(-(-pages // k))) * k
         self.stats.pages_live += live
         self.stats.pages_walked += walked
         rows_valid = rows_computed = 0
@@ -400,11 +408,11 @@ class ContinuousServer:
             # keep the draft cache in lockstep: same tokens into the draft
             # pool, logits discarded — this is what makes a later sharer's
             # draft cache warm over shared prefix pages too
-            with self._dispatch([(lengths, n_valid)]):
+            with self._dispatch([(lengths, n_valid)], C):
                 _, self.pool.draft_pages = self._draft_fn(
                     self.draft_params, tokens, self.pool.draft_pages,
                     self.pool.tables, lengths, n_valid)
-        with self._dispatch([(lengths, n_valid)], prefill=True):
+        with self._dispatch([(lengths, n_valid)], C, prefill=True):
             logits, self.pool.pages = self._step_fn(
                 self.params, tokens, self.pool.pages,
                 self.pool.tables, lengths, n_valid)
@@ -441,7 +449,7 @@ class ContinuousServer:
             for i in idx:
                 tokens[i, 0] = self.slots[i].pending
                 n_valid[i] = 1
-        with self._dispatch([(self.pool.lengths, n_valid)]):
+        with self._dispatch([(self.pool.lengths, n_valid)], 1):
             logits, self.pool.pages = self._step_fn(
                 self.params, tokens, self.pool.pages,
                 self.pool.tables, self.pool.lengths, n_valid)
@@ -485,7 +493,7 @@ class ContinuousServer:
                 keff_arr[i] = k_eff[i]
         # draft step j writes one token for slots with j < k_eff - 1
         with self._dispatch([(base + j, (j < keff_arr - 1).astype(np.int32))
-                             for j in range(k - 1)]):
+                             for j in range(k - 1)], 1):
             drafted, self.pool.draft_pages = self._propose_fn(
                 self.draft_params, self.pool.draft_pages, self.pool.tables,
                 base, t0, keff_arr)
@@ -500,7 +508,7 @@ class ContinuousServer:
             for i in idx:
                 tokens[i, :k_eff[i]] = props[i]
                 n_valid[i] = k_eff[i]
-        with self._dispatch([(base, n_valid)]):
+        with self._dispatch([(base, n_valid)], k):
             vlogits, self.pool.pages = self._verify_fn(
                 self.params, tokens, self.pool.pages,
                 self.pool.tables, base, n_valid)

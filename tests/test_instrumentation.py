@@ -46,7 +46,8 @@ def test_server_counts_rows_and_pages_by_hand(served):
             prefill A[4:6]          rows 2/8  live 2    (A's 1st token)
     tick 3  decode A at 6           live 2
     tick 4  decode A at 7           live 2              (A done)
-    Five calls walk 2 x 8 pages each."""
+    The kernel walks a valid slot's live pages in whole blocks of 8 (the
+    whole table here): six slot-calls walk 8 pages each."""
     cfg = served[0]
     srv = _server(served)
     srv.submit(Request(rid=0, prompt=_prompt(cfg, 6, 1), max_new_tokens=3))
@@ -55,7 +56,39 @@ def test_server_counts_rows_and_pages_by_hand(served):
     st = srv.stats
     assert srv.clock == 4
     assert (st.prefill_rows_valid, st.prefill_rows_computed) == (9, 16)
-    assert (st.pages_live, st.pages_walked) == (9, 80)
+    assert (st.pages_live, st.pages_walked) == (9, 48)
+
+
+def test_pages_walked_follow_the_kernel_blocks(served):
+    """One mixed tick over 4 slots of a 64-page table (page 4): A decodes at
+    6 tokens, B prefills its second 8-row chunk (16 tokens), C is admitted
+    and prefills its first (8 tokens), slot 3 is empty. The kernel walks
+    each valid slot's live pages in whole blocks of ``block_pages`` pages,
+    for the decode call's rows and the chunk call's; the idle slot and the
+    slots a call does not advance walk nothing."""
+    from repro.kernels.paged_attention.paged_attention import block_pages
+    cfg, model, params = served
+    srv = ContinuousServer(model, params, max_batch=4, max_len=256,
+                           page_size=4, prefill_chunk=8)
+    srv.submit(Request(rid=0, prompt=_prompt(cfg, 5, 0), max_new_tokens=8))
+    srv.submit(Request(rid=1, prompt=_prompt(cfg, 30, 1), max_new_tokens=8))
+    srv.step()                      # A and B prefill their first chunk
+    srv.step()                      # A decodes, B prefills its second
+    srv.submit(Request(rid=2, prompt=_prompt(cfg, 8, 2), max_new_tokens=8))
+    st = srv.stats
+    live0, walked0 = st.pages_live, st.pages_walked
+    srv.step()
+    pool = srv.pool.pages["k_pages"]
+    G, D, item = cfg.n_heads // cfg.n_kv_heads, pool.shape[-1], \
+        pool.dtype.itemsize
+    k_dec = block_pages(G, D, item, 4, 64)
+    k_pre = block_pages(G * 8, D, item, 4, 64)
+    assert 1 < k_pre <= k_dec < 64
+    # decode: A at 6 + 1 tokens; prefill: B 16 + 8, C 0 + 8
+    live = 2 + (6 + 2)
+    walked = k_dec + 2 * k_pre
+    assert (st.pages_live - live0, st.pages_walked - walked0) == (live, walked)
+    assert st.pages_live <= st.pages_walked
 
 
 def test_request_stamps_are_ordered(served):
@@ -118,9 +151,9 @@ def test_serving_tick_spans_nest(served):
     # the calls' counter increments ride on the dispatch spans
     args = [a for _, _, _, a in sorted(by["serve.dispatch"],
                                        key=lambda t: t[1])]
-    assert args[0] == {"pages_live": 1, "pages_walked": 16,
+    assert args[0] == {"pages_live": 1, "pages_walked": 8,
                        "rows_valid": 0, "rows_computed": 0}
-    assert args[1] == {"pages_live": 1, "pages_walked": 16,
+    assert args[1] == {"pages_live": 1, "pages_walked": 8,
                        "rows_valid": 3, "rows_computed": 8}
     mark, = by["serve.first_token"]
     assert _inside(mark, by["serve.sample"])

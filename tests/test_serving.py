@@ -74,35 +74,93 @@ def _paged_inputs(B, C, Hq, Hkv, D, N, P, nP, seed=0):
     return q, kp, vp, tables
 
 
-@pytest.mark.parametrize("B,C,Hq,Hkv,D,N,P,nP,q_start", [
-    (3, 4, 4, 2, 16, 12, 8, 3, [5, 0, 17]),
-    (2, 1, 4, 4, 32, 8, 16, 2, [9, 30]),      # decode shape, MHA
-    (1, 8, 8, 2, 16, 6, 8, 4, [13]),          # chunk, GQA group 4
-    (3, 4, 4, 2, 16, 12, 8, 3, [-1, 3, 8]),   # row 0 fully masked (inactive)
-    (2, 4, 2, 1, 16, 5, 4, 4, [15, 15]),      # slot completely full
+# slots of the cells' cases: no new rows; no cached tokens; live pages ending
+# mid-block; the whole table. P = 16, so a block is 8 pages (128 tokens)
+@pytest.mark.parametrize("B,C,Hq,Hkv,D,N,P,nP,q_start,n_valid", [
+    (3, 4, 4, 2, 16, 12, 8, 3, [5, 0, 17], [4, 4, 4]),
+    (2, 1, 4, 4, 32, 8, 16, 2, [9, 30], [1, 1]),        # decode shape, MHA
+    (1, 8, 8, 2, 16, 6, 8, 4, [13], [8]),               # chunk, GQA group 4
+    (3, 4, 4, 2, 16, 12, 8, 3, [-1, 3, 8], [0, 4, 4]),  # slot 0 inactive
+    (2, 4, 2, 1, 16, 5, 4, 4, [15, 15], [1, 1]),        # slot completely full
+    # serve-chat (qwen2.5-3b: GQA G = 8, D = 128): decode, prefill chunk
+    (4, 1, 16, 2, 128, 48, 16, 12, [40, 0, 150, 191], [0, 1, 1, 1]),
+    (4, 128, 16, 2, 128, 48, 16, 12, [50, 0, 30, 64], [0, 128, 50, 128]),
+    # serve-decode (phi3-mini: MHA, D = 96): decode, prefill chunk
+    (4, 1, 4, 4, 96, 48, 16, 12, [40, 0, 150, 191], [0, 1, 1, 1]),
+    (4, 128, 4, 4, 96, 48, 16, 12, [50, 0, 30, 64], [0, 128, 50, 128]),
+    # serve-docqa (zamba2 shared blocks: MHA, D = 256): a 256-row chunk
+    (4, 256, 2, 2, 256, 80, 16, 20, [40, 0, 10, 64], [0, 256, 100, 256]),
 ])
 def test_paged_attention_pallas_bit_identical_to_oracle(B, C, Hq, Hkv, D, N,
-                                                        P, nP, q_start):
+                                                        P, nP, q_start,
+                                                        n_valid):
     """Not allclose: BIT equality. The kernel body and the oracle share the
-    _page_step/_mask helpers and both run jitted, so any divergence means
-    the Pallas kernel stopped computing the documented recurrence."""
+    _block_step/_mask helpers and walk the same blocks of the same page ids,
+    both jitted, so any divergence means the Pallas kernel stopped
+    computing the documented recurrence. The gather path agrees on the
+    valid rows; a slot with no new rows comes out as zeros."""
     from repro.kernels.paged_attention import ref as pref
     from repro.kernels.paged_attention.paged_attention import \
         paged_attention_pallas
     q, kp, vp, tables = _paged_inputs(B, C, Hq, Hkv, D, N, P, nP)
     qs = jnp.asarray(q_start, jnp.int32)
-    o_pal = paged_attention_pallas(q, kp, vp, tables, qs, interpret=True)
-    o_ref = pref.paged_attention_oracle(q, kp, vp, tables, qs)
+    nv = jnp.asarray(n_valid, jnp.int32)
+    o_pal = paged_attention_pallas(q, kp, vp, tables, qs, nv, interpret=True)
+    o_ref = pref.paged_attention_oracle(q, kp, vp, tables, qs, nv)
     np.testing.assert_array_equal(np.asarray(o_pal), np.asarray(o_ref))
+    o_g = np.asarray(pref.paged_attention_gather(q, kp, vp, tables, qs, nv))
+    for b, n in enumerate(n_valid):
+        np.testing.assert_allclose(o_g[b, :n], np.asarray(o_ref)[b, :n],
+                                   atol=2e-6)
+        if n == 0:
+            assert not np.asarray(o_pal)[b].any()
+            assert not o_g[b].any()
 
 
 def test_paged_attention_gather_matches_oracle():
     from repro.kernels.paged_attention import ref as pref
     q, kp, vp, tables = _paged_inputs(3, 4, 4, 2, 16, 12, 8, 3)
     qs = jnp.asarray([5, 0, 17], jnp.int32)
-    o_g = pref.paged_attention_gather(q, kp, vp, tables, qs)
-    o_ref = pref.paged_attention_oracle(q, kp, vp, tables, qs)
+    nv = jnp.asarray([4, 4, 4], jnp.int32)
+    o_g = pref.paged_attention_gather(q, kp, vp, tables, qs, nv)
+    o_ref = pref.paged_attention_oracle(q, kp, vp, tables, qs, nv)
     np.testing.assert_allclose(np.asarray(o_g), np.asarray(o_ref), atol=2e-6)
+
+
+def test_paged_attention_reads_no_dead_page():
+    """The walk copies only pages named in the first live entries of a
+    slot's own table row. Every other page a table names is NaN: the table
+    entries past a slot's live pages (some naming another slot's live
+    pages, as stale rows do), and every page of a slot with no new rows. A
+    kernel that copied one would carry the NaN through ``p @ v`` even where
+    the mask hides it; the valid rows are finite and bit-equal to the
+    oracle's with those pages zeroed, and the idle slot's rows are zeros."""
+    from repro.kernels.paged_attention import ref as pref
+    from repro.kernels.paged_attention.paged_attention import (
+        live_pages, paged_attention_pallas)
+    B, C, Hq, Hkv, D, P, nP = 4, 4, 4, 2, 32, 16, 12
+    q, kp, vp, _ = _paged_inputs(B, C, Hq, Hkv, D, B * nP, P, nP)
+    tables = np.arange(B * nP, dtype=np.int32).reshape(B, nP)
+    q_start = np.asarray([30, 0, 70, 188], np.int32)
+    n_valid = np.asarray([0, 4, 4, 4], np.int32)   # slot 0 idle
+    live = np.asarray(live_pages(q_start, n_valid, P, nP))
+    assert live.tolist() == [0, 1, 5, 12]       # slot 2 ends mid-block
+    dead = [int(p) for b in range(B) for p in tables[b, live[b]:]]
+    tables[1, 3:6] = tables[3, :3]              # another slot's live pages
+    nan = jnp.zeros((B * nP,), bool).at[jnp.asarray(dead)].set(True)
+    nan = nan[:, None, None, None]
+    args = (jnp.asarray(tables), jnp.asarray(q_start), jnp.asarray(n_valid))
+    o_pal = np.asarray(paged_attention_pallas(
+        q, jnp.where(nan, jnp.nan, kp), jnp.where(nan, jnp.nan, vp), *args,
+        interpret=True))
+    o_ref = np.asarray(pref.paged_attention_oracle(
+        q, jnp.where(nan, 0.0, kp), jnp.where(nan, 0.0, vp), *args))
+    o_g = np.asarray(pref.paged_attention_gather(
+        q, jnp.where(nan, jnp.nan, kp), jnp.where(nan, jnp.nan, vp), *args))
+    assert np.isfinite(o_pal).all() and not o_pal[0].any()
+    np.testing.assert_array_equal(o_pal[1:], o_ref[1:])
+    assert np.isfinite(o_g).all()
+    np.testing.assert_allclose(o_g[1:], o_ref[1:], atol=2e-6)
 
 
 def test_paged_reset_parity_and_isolation():

@@ -71,13 +71,13 @@ def _cases(P):
     key, sc, silo = ((2,), u32), ((), f32), ((), i32)
     D, Hq, Hkv = CONFIG.d_head, CONFIG.n_heads, CONFIG.n_kv_heads
     n_pages = SLOTS * PAGES_PER_SLOT
-    pool = ((n_pages, Hkv, PAGE, D), f32)
     layers_pool = ((2, n_pages, Hkv, PAGE, D), f32)
     tables, qs = ((SLOTS, PAGES_PER_SLOT), i32), ((SLOTS,), i32)
 
-    def attention(C):
+    def attention(C, Hq=Hq, Hkv=Hkv, D=D):
+        pool = ((n_pages, Hkv, PAGE, D), f32)
         return (paged_attention_pallas,
-                [((SLOTS, C, Hq, D), f32), pool, pool, tables, qs])
+                [((SLOTS, C, Hq, D), f32), pool, pool, tables, qs, qs])
 
     return {
         "clip_sum": (clip_sum_pallas, [((N_SILOS, P), f32), sc]),
@@ -96,6 +96,10 @@ def _cases(P):
         "per_example_sumsq": (per_example_sumsq, [((N_SILOS, P), f32)]),
         "paged_attention_decode": attention(1),
         "paged_attention_prefill_chunk": attention(128),
+        # phi3-mini's MHA decode (32 heads of 96, the pool's 128 lanes) and
+        # a Zamba2 shared block's 256-row chunk (32 heads of 224 in 256)
+        "paged_attention_mha_decode": attention(1, 32, 32, 128),
+        "paged_attention_mha256_prefill_chunk": attention(256, 32, 32, 256),
         "paged_reset": (paged_reset_pallas,
                         [layers_pool, layers_pool, ((PAGES_PER_SLOT,), i32)]),
         "paged_rollback": (paged_rollback_pallas,
@@ -107,7 +111,8 @@ def _cases(P):
 @pytest.mark.parametrize("kernel", [
     "clip_sum", "clip_mask", "noise_batch", "zsmask", "clip_accumulate",
     "per_example_sumsq", "paged_attention_decode",
-    "paged_attention_prefill_chunk", "paged_reset", "paged_rollback"])
+    "paged_attention_prefill_chunk", "paged_attention_mha_decode",
+    "paged_attention_mha256_prefill_chunk", "paged_reset", "paged_rollback"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, packed_size):
     fn, shapes = _cases(packed_size)[kernel]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
