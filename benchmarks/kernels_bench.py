@@ -7,9 +7,8 @@ mode on CPU (correctness-path overhead, not TPU speed; the roofline table
 covers TPU projections) and are skipped off-TPU by default — set
 ``BENCH_ALL_IMPLS=1`` to include them.
 
-The ``dp_tree`` section is the headline perf comparison for the packed
-flat-buffer DP engine: per-leaf dispatch (2+ launches per pytree leaf) vs
-the packed path (O(1) dispatches over one flat buffer) across leaf counts.
+The ``dp_tree`` section times the packed flat-buffer DP engine (O(1)
+dispatches over one flat buffer) across leaf counts.
 """
 from __future__ import annotations
 
@@ -101,13 +100,14 @@ def run():
         emit(f"kernels/zsmask_{impl}_1m", timeit(f, gflat),
              impl=impl, shape="D=1048576")
 
-    # packed flat-buffer engine vs per-leaf dispatch across leaf counts:
-    # the DP hot path on synthetic gradient pytrees. dp_tree isolates the
-    # clip+sum op; zsmask_tree isolates the mask; dp_pipeline is the headline
-    # comparison — the full per-step clip+sum+corrected-noise composition as
-    # the step builders run it (packed stays packed between the ops, so the
-    # pack/unpack cost is paid once per step, not once per op).
+    # the packed flat-buffer engine across leaf counts: the DP hot path on
+    # synthetic gradient pytrees. dp_tree isolates the clip+sum op;
+    # mask_tree isolates the mask; dp_pipeline is the full per-step
+    # clip+sum+corrected-noise composition as the step builders run it
+    # (packed stays packed between the ops, so the pack/unpack cost is paid
+    # once per step, not once per op).
     from repro.core import barrier as barrier_mod, flatbuf
+    from repro.kernels.dp_fused import ops as fused_ops
     from repro.core.noise_correction import NoiseState
     from repro.configs.base import PrivacyConfig
 
@@ -120,17 +120,14 @@ def run():
     for n_leaves in (8, 64, 256):
         tree = _synthetic_tree(ks[3], n_leaves, B, 64)
         shape = f"leaves={n_leaves},B={B}"
-        for impl in ("perleaf", "packed"):
-            f = jax.jit(lambda t, i=impl: dops.clip_and_sum_tree(t, 1.0,
-                                                                 impl=i)[0])
-            emit(f"kernels/dp_tree_{impl}_l{n_leaves}", timeit(f, tree),
-                 impl=impl, shape=shape)
+        f = jax.jit(lambda t: fused_ops.packed_clip_and_sum(t, 1.0)[0])
+        emit(f"kernels/dp_tree_packed_l{n_leaves}", timeit(f, tree),
+             impl="packed", shape=shape)
         elem_tree = {k: v[0] for k, v in tree.items()}
-        for impl in ("perleaf", "packed"):
-            f = jax.jit(lambda t, i=impl: masking.pairwise_mask_tree(
-                t, kr, kx, 0, 4, 1.0, 8.0, impl=i))
-            emit(f"kernels/zsmask_tree_{impl}_l{n_leaves}",
-                 timeit(f, elem_tree), impl=impl, shape=shape)
+        f = jax.jit(lambda t: masking.pairwise_mask_tree(
+            t, kr, kx, 0, 4, 1.0, 8.0))
+        emit(f"kernels/mask_tree_packed_l{n_leaves}",
+             timeit(f, elem_tree), impl="packed", shape=shape)
 
         # the dp_pipeline rows run one (n, P) buffer through the two
         # central-tier constructions at the repo's canonical 4-silo
@@ -142,7 +139,7 @@ def run():
         # all-active set known at trace time takes the static fast path,
         # whose only remaining cost over ``packed`` is the per-silo noise
         # streams the cross-tier bit-parity contract requires — CI gates
-        # that overhead at 1.25x (see ``check``). The four rows are
+        # that overhead at 1.25x (see ``check``). The three rows are
         # measured interleaved: they compare close variants of one graph,
         # and host scheduling noise between separate timeit calls would
         # dwarf the effect.
@@ -157,14 +154,7 @@ def run():
         active_full = jnp.ones((n_silos,), jnp.bool_)
         pshape = f"leaves={n_leaves},n={n_silos}"
 
-        def pipeline_perleaf(t):
-            summed, norms = dops.clip_and_sum_tree(t, 1.0, impl="perleaf")
-            noisy, _ = barrier_mod.fused_noise(summed, priv, keys, nstate,
-                                               1.0, impl="perleaf")
-            return noisy
-
         def pipeline_packed(t):
-            from repro.kernels.dp_fused import ops as fused_ops
             summed, norms = fused_ops.clip_sum_packed(
                 flatbuf.pack(batch_layout, t), 1.0)
             noisy, _ = barrier_mod.fused_noise_packed(summed, priv, keys,
@@ -181,18 +171,15 @@ def run():
             return noisy
 
         us = timeit_interleaved([
-            (jax.jit(pipeline_perleaf), (silo_tree,)),
             (jax.jit(pipeline_packed), (silo_tree,)),
             (jax.jit(pipeline_active), (silo_tree, active_drop)),
             (jax.jit(lambda t: pipeline_active(t, active_full)), (silo_tree,)),
         ])
-        emit(f"kernels/dp_pipeline_perleaf_l{n_leaves}", us[0],
-             impl="perleaf", shape=pshape)
-        emit(f"kernels/dp_pipeline_packed_l{n_leaves}", us[1],
+        emit(f"kernels/dp_pipeline_packed_l{n_leaves}", us[0],
              impl="packed", shape=pshape)
-        emit(f"kernels/dp_pipeline_active_set_l{n_leaves}", us[2],
+        emit(f"kernels/dp_pipeline_active_set_l{n_leaves}", us[1],
              impl="packed", shape=pshape + f",k={n_silos - 1}/{n_silos}")
-        emit(f"kernels/dp_pipeline_active_static_l{n_leaves}", us[3],
+        emit(f"kernels/dp_pipeline_active_static_l{n_leaves}", us[2],
              impl="packed", shape=pshape + f",k={n_silos}/{n_silos} (static)")
 
 

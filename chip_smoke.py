@@ -69,7 +69,7 @@ AGG_ATOL = 1e-3
 LOSS_RTOL = 1e-3      # per-silo losses from two compiled programs
 FOUR_CHIP_LR = 1.0    # SGD: the update is the aggregate itself, / n_silos
 
-PATH_KERNELS = {"dp_noise_tree", "dp_clip_accumulate", "dp_fused_noise_batch",
+PATH_KERNELS = {"dp_clip_accumulate", "dp_fused_noise_batch",
                 "paged_attention", "paged_reset", "paged_rollback"}
 
 
@@ -343,9 +343,9 @@ def one_chip(jax, args, check, compiles):
         model, params = train_phase(jax, cfg, args.seed, check, compiles)
         serve_phase(jax, model, params, args.seed, check)
     say(f"kernels: {{{', '.join(f'{k}: {sorted(v)}' for k, v in sorted(resolved.items()))}}}")
-    bad = {k: v for k, v in resolved.items() if not v <= {"pallas", "packed"}}
+    bad = {k: v for k, v in resolved.items() if not v <= {"pallas"}}
     missing = PATH_KERNELS - set(resolved)
-    check("kernels_resolved_pallas_or_packed", not bad and not missing,
+    check("kernels_resolved_pallas", not bad and not missing,
           f"fell back: {bad or 'none'}, not reached: "
           f"{sorted(missing) or 'none'}")
 

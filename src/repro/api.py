@@ -17,7 +17,8 @@ Arch ids accept both the assignment spelling (``qwen2.5-3b``) and the
 module-style spelling (``qwen25_3b``). ``Session`` is the integration point
 the dispatch registry, autotuning cache and additional backends plug into;
 kernel selection inside a session is still governed by
-``repro.kernels.dispatch`` (``force_impl`` / ``REPRO_KERNEL_IMPL``).
+``repro.kernels.dispatch`` (``impl="auto"``; tests substitute a variant with
+``force_impl``).
 """
 from __future__ import annotations
 

@@ -1,20 +1,15 @@
-"""The privacy barrier (paper §4): keys, dynamic-bound protocol and the
-tree-level corrected-noise kernel library.
+"""The privacy barrier (paper §4): keys and the dynamic-bound protocol.
 
 The per-tier composition of clipping, zero-sum masking and corrected DP
-noise lives in ONE place now — :class:`repro.core.dp_pipeline.DPPipeline`
+noise lives in ONE place — :class:`repro.core.dp_pipeline.DPPipeline`
 (stage graph ``norms -> dynamic_bound -> clip_scale -> masked_aggregate ->
 corrected_noise`` with an explicit participation set). This module keeps the
 pieces the engine and its callers share:
 
 * ``BarrierKeys`` / ``step_keys`` — the admin's 32-bytes-per-step key fanout.
 * ``dynamic_bound_from_percentiles`` — the §4.3 percentile-bound selection.
-* the ``dp_noise_tree`` registry kernel (``fused_noise`` /
-  ``fused_noise_packed``): post-aggregate corrected noise as a standalone
-  tree-level op. The packed variant regenerates noise in VMEM from 32-byte
-  keys; the per-leaf variant stays load-bearing for FSDP-sharded
-  accumulators, where packing would gather the full parameter buffer onto
-  every device.
+* ``fused_noise_packed`` — post-aggregate corrected noise on a packed
+  buffer from a single stream, regenerated in VMEM from 32-byte keys.
 * ``aggregate_noise_from_streams`` — test oracle for the engine's per-silo
   stream construction.
 """
@@ -26,9 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import PrivacyConfig
-from repro.core import clipping, flatbuf, masking, noise_correction
+from repro.core import clipping, flatbuf, masking
 from repro.core.noise_correction import NoiseState
-from repro.kernels.dispatch import kernel_variant, REGISTRY
 from repro.kernels.dp_fused import ops as fused_ops
 
 
@@ -90,9 +84,7 @@ def dynamic_bound_from_percentiles(percentiles_all, priv: PrivacyConfig, key):
 
 
 # ---------------------------------------------------------------------------
-# Post-aggregate corrected noise (the dp_noise_tree registry kernel)
-
-NOISE = "dp_noise_tree"
+# Post-aggregate corrected noise
 
 
 def fused_noise_packed(g_packed, priv: PrivacyConfig, keys: BarrierKeys,
@@ -109,49 +101,6 @@ def fused_noise_packed(g_packed, priv: PrivacyConfig, keys: BarrierKeys,
         use_prev=priv.noise_lambda > 0.0, impl=impl)
     new_state = NoiseState(prev_key=kx, has_prev=jnp.ones((), jnp.bool_))
     return noisy, new_state
-
-
-@kernel_variant(NOISE, "packed", priority=100,
-                auto_predicate=fused_ops.prefers_packed,
-                doc="packed flat-buffer corrected noise, one fused dispatch")
-def _noise_packed(g_sum, priv, keys, noise_state, clip_bound, inner="auto"):
-    layout = flatbuf.layout_of(g_sum)
-    packed = flatbuf.pack(layout, g_sum)
-    noisy, new_state = fused_noise_packed(packed, priv, keys, noise_state,
-                                          clip_bound, impl=inner)
-    return flatbuf.unpack(layout, noisy), new_state
-
-
-@kernel_variant(NOISE, "perleaf", priority=50,
-                doc="per-leaf jax.random noise (keeps FSDP sharding)")
-def _noise_perleaf(g_sum, priv, keys, noise_state, clip_bound, inner="auto"):
-    sigma_c = priv.sigma * clip_bound
-    noise, new_state = noise_correction.corrected_noise(
-        g_sum, keys.key_xi, noise_state, sigma_c, priv.noise_lambda)
-    noisy = jax.tree.map(lambda g, n: (g.astype(jnp.float32) + n).astype(g.dtype),
-                         g_sum, noise)
-    return noisy, new_state
-
-
-@kernel_variant(NOISE, "pallas", priority=20,
-                doc="legacy name: packed engine, Pallas inner kernel")
-def _noise_pallas(g_sum, priv, keys, noise_state, clip_bound):
-    return _noise_packed(g_sum, priv, keys, noise_state, clip_bound,
-                         inner="pallas")
-
-
-@kernel_variant(NOISE, "jnp", priority=10,
-                doc="legacy name: per-leaf jax.random noise")
-def _noise_jnp(g_sum, priv, keys, noise_state, clip_bound):
-    return _noise_perleaf(g_sum, priv, keys, noise_state, clip_bound)
-
-
-def fused_noise(g_sum, priv: PrivacyConfig, keys: BarrierKeys,
-                noise_state: NoiseState, clip_bound, impl: str = "auto"):
-    """g_sum: already-aggregated clipped gradient sum. Adds corrected DP noise
-    xi_t - lam*xi_{t-1} at scale sigma*C."""
-    return REGISTRY.dispatch(NOISE, impl, fused_ops.tree_ctx(g_sum),
-                             g_sum, priv, keys, noise_state, clip_bound)
 
 
 def aggregate_noise_from_streams(template, keys: BarrierKeys, n_silos: int,

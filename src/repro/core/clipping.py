@@ -13,7 +13,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dp_clip import ops as clip_ops
+from repro.kernels.dp_fused import ops as fused_ops
 
 
 def global_norm(tree) -> jax.Array:
@@ -37,14 +37,16 @@ def clip_tree(tree, clip_bound) -> tuple:
 
 def per_example_clipped_grad(loss_fn: Callable, params, batch, clip_bound,
                              impl: str = "auto"):
-    """DP-SGD per-example clipping: vmapped per-example grads, fused
-    clip-and-accumulate (kernels/dp_clip). Returns (sum_grads, per_ex_norms,
-    mean_loss). ``batch`` leaves have a leading example axis."""
+    """DP-SGD per-example clipping: vmapped per-example grads, one fused
+    clip-and-sum dispatch on their packed buffer (kernels/dp_fused; ``impl``
+    picks its inner variant). Returns (sum_grads, per_ex_norms, mean_loss).
+    ``batch`` leaves have a leading example axis."""
     def one(ex):
         return jax.value_and_grad(loss_fn)(params, jax.tree.map(lambda x: x[None], ex))
 
     losses, grads = jax.vmap(one)(batch)  # grads: leaves (B, ...)
-    summed, norms = clip_ops.clip_and_sum_tree(grads, clip_bound, impl=impl)
+    summed, norms = fused_ops.packed_clip_and_sum(grads, clip_bound,
+                                                  impl=impl)
     return summed, norms, jnp.mean(losses)
 
 

@@ -4,31 +4,27 @@ CITADEL++'s core guarantee — the model updater only ever sees
 
     sum_i clip(g_i) + sigma*C*(xi_t - lambda*xi_{t-1})
 
-— used to be implemented once per execution tier (vmap-fused, silo-serial
-scan, shard_map barrier, and the TEE wire protocol), each copy re-deciding
-packed-vs-perleaf and re-deriving streams. :class:`DPPipeline` is the one
-engine all four tiers now build on. It is constructed once per step function
-from a :class:`~repro.configs.base.PrivacyConfig` + a
+is implemented once, here, for all four execution tiers (vmap-fused,
+silo-serial scan, shard_map barrier, and the TEE wire protocol).
+:class:`DPPipeline` is constructed once per step function from a
+:class:`~repro.configs.base.PrivacyConfig` + a
 :class:`~repro.core.flatbuf.PackedLayout` and exposes the stage graph
 
     norms -> dynamic_bound -> clip_scale -> masked_aggregate -> corrected_noise
 
-with two cross-cutting decisions made exactly once:
+The fused, barrier and wire tiers run every stage on the packed flat buffer
+through the fused kernels, so all silos of a session draw from one stream
+family. The scan tier alone draws per-leaf noise on its FSDP-sharded
+accumulator (:meth:`DPPipeline.corrected_noise_tree`).
 
-* **Execution policy** (``packed`` | ``perleaf``, inner kernel impl): resolved
-  through the kernel-dispatch REGISTRY at construction (honouring
-  ``force_impl`` / ``REPRO_KERNEL_IMPL`` on ``dp_noise_tree``). One policy
-  governs both the mask and the noise construction — all silos of a session
-  must draw from the same stream family, so the old per-stage resolution was
-  a correctness hazard, not a feature.
-* **Participation set**: every stage takes ``active``, an ``(n_silos,)`` bool
-  mask of the silos actually contributing this step. Zero-sum masks are
-  generated over the ring of *active* silos (``next_active`` skips dropped
-  members, so the r-terms still telescope to zero for any k <= n), each
-  active silo's fresh-noise share is ``sigma_c/sqrt(k)`` (aggregate noise std
-  stays exactly ``sigma_c`` for any k), and the aggregate is divided by the
-  actual contribution count — elastic membership without touching the
-  guarantee.
+**Participation set**: every stage takes ``active``, an ``(n_silos,)`` bool
+mask of the silos actually contributing this step. Zero-sum masks are
+generated over the ring of *active* silos (``next_active`` skips dropped
+members, so the r-terms still telescope to zero for any k <= n), each
+active silo's fresh-noise share is ``sigma_c/sqrt(k)`` (aggregate noise std
+stays exactly ``sigma_c`` for any k), and the aggregate is divided by the
+actual contribution count — elastic membership without touching the
+guarantee.
 
 Three mask constructions run through the same stages: ``pairwise`` (the
 key-derived zero-sum ring above), ``admin`` (the paper-faithful O(n*P) mask
@@ -57,8 +53,6 @@ per protocol message. Neither re-implements any of the math.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,11 +62,8 @@ from repro.core import clipping, flatbuf, masking, noise_correction
 from repro.core.barrier import BarrierKeys, dynamic_bound_from_percentiles
 from repro.core.flatbuf import PackedLayout, scoped
 from repro.core.noise_correction import NoiseState
-from repro.kernels.dispatch import REGISTRY
 from repro.kernels.dp_clip import ops as clip_ops
 from repro.kernels.dp_fused import ops as fused_ops
-
-NOISE_TREE = "dp_noise_tree"
 
 
 @jax.jit
@@ -111,34 +102,11 @@ def _static_all_true(vec) -> bool:
         and bool(np.all(np.asarray(vec)))
 
 
-@dataclass(frozen=True)
-class ExecutionPolicy:
-    """How the pipeline executes: ``packed`` runs every stage on the flat
-    buffer through the fused kernels (``inner`` picks pallas/jnp/auto for the
-    tensor-level dispatch); ``perleaf`` keeps the legacy per-leaf jax.random
-    construction (load-bearing for FSDP-sharded accumulators, where packing
-    would gather the full parameter buffer onto every device)."""
-
-    mode: str   # 'packed' | 'perleaf'
-    inner: str  # tensor-kernel impl under packed: 'auto' | 'pallas' | 'jnp'
-
-
-def resolve_policy(request: str, n_leaves: int) -> ExecutionPolicy:
-    """Resolve the execution policy through the registry — exactly once per
-    pipeline. ``force_impl(...)`` / ``REPRO_KERNEL_IMPL=dp_noise_tree=...``
-    override ``request`` as usual; legacy impl names map onto the two modes
-    (pallas -> packed/pallas, jnp -> perleaf)."""
-    name = REGISTRY.resolve(NOISE_TREE, request, {"n_leaves": n_leaves}).name
-    if name in ("perleaf", "jnp"):
-        return ExecutionPolicy("perleaf", "jnp")
-    return ExecutionPolicy("packed", "pallas" if name == "pallas" else "auto")
-
-
 class DPPipeline:
     """One guarded aggregation engine, four mesh placements (DESIGN.md §2)."""
 
     def __init__(self, priv: PrivacyConfig, layout: PackedLayout,
-                 n_silos: int, policy: str = "packed"):
+                 n_silos: int):
         if priv.mask_mode not in ("pairwise", "admin", "none"):
             raise ValueError(
                 f"DPPipeline supports mask_mode pairwise|admin|none, got "
@@ -146,7 +114,6 @@ class DPPipeline:
         self.priv = priv
         self.layout = layout
         self.n_silos = int(n_silos)
-        self.policy = resolve_policy(policy, layout.n_leaves)
 
     # -- participation set --------------------------------------------------
     def full_active(self) -> jax.Array:
@@ -287,8 +254,7 @@ class DPPipeline:
         dispatch. Central tiers elide the zero-sum masks (they cancel in the
         aggregate by construction); the per-silo view of this stage is
         :meth:`silo_contribution`."""
-        impl = self.policy.inner if self.policy.mode == "packed" else "auto"
-        return clip_ops.clipped_sum(stacked, scales, impl=impl)
+        return clip_ops.clipped_sum(stacked, scales)
 
     @scoped("dp.mask")
     def admin_closing_row(self, template, active, keys: BarrierKeys,
@@ -338,26 +304,24 @@ class DPPipeline:
         only; see :meth:`admin_closing_row`) — used instead of regenerating
         the row locally.
 
-        ``xi``/``xp``: externally drawn noise streams (packed pairwise mode
-        only — the speculative wire tier draws them via :meth:`noise_stream`
-        and reuses its round-t xi as round-(t+1)'s xi_prev, since the admin
+        ``xi``/``xp``: externally drawn noise streams (pairwise mode only —
+        the speculative wire tier draws them via :meth:`noise_stream` and
+        reuses its round-t xi as round-(t+1)'s xi_prev, since the admin
         carries exactly that key forward). ``None`` draws in-graph.
 
-        Returns a packed (P,) buffer under the packed policy (psum it, then
-        :meth:`finalize`), a pytree under perleaf (which supports the full
-        ring only — elastic runs require the packed policy)."""
+        Returns a packed (P,) buffer under pairwise masks (psum it, then
+        :meth:`finalize`), a pytree under the admin and none constructions."""
         priv = self.priv
         silo = jnp.asarray(silo, jnp.int32)
         static = is_static_full(active)
         gate = 1.0 if static else active[silo].astype(jnp.float32)
         sigma_c = priv.sigma * jnp.asarray(bound, jnp.float32)
         use_prev = priv.noise_lambda > 0.0
-        if (xi is not None or xp is not None) and (
-                priv.mask_mode != "pairwise"
-                or self.policy.mode != "packed"):
+        if (xi is not None or xp is not None) and \
+                priv.mask_mode != "pairwise":
             raise ValueError(
-                "external xi/xp streams only apply to the packed pairwise "
-                "construction (admin/none/perleaf draw their own)")
+                "external xi/xp streams only apply to the pairwise "
+                "construction (admin/none draw their own)")
         if priv.mask_mode == "none":
             # confidentiality-only sync: clipped gradient, no DP terms
             scaled = scale * gate
@@ -405,30 +369,6 @@ class DPPipeline:
         pa_gate = 1.0 if _static_all_true(pa) \
             else pa[silo].astype(jnp.float32)
         lam_gate = priv.noise_lambda * hp * gate * pa_gate
-        if self.policy.mode == "perleaf":
-            # legacy per-leaf stream family; the ring is static (full), so a
-            # partial participation set would leave uncancelled +-B*r terms
-            # in the aggregate. build_train_step rejects elastic barrier
-            # runs up front; the wire tier passes concrete masks, caught here
-            if not isinstance(active, jax.core.Tracer) \
-                    and not bool(jnp.all(active)):
-                raise ValueError(
-                    "the per-leaf mask family only builds the full static "
-                    "ring; dropping silos needs the packed policy (lift the "
-                    "dp_noise_tree=perleaf override for elastic runs)")
-            scaled = jax.tree.map(
-                lambda x: (x.astype(jnp.float32) * scale).astype(x.dtype),
-                g_tree)
-            masked = masking.pairwise_mask_tree(
-                scaled, keys.key_r, keys.key_xi, silo, self.n_silos,
-                sigma_c, priv.mask_scale * sigma_c, impl="perleaf")
-            if use_prev:
-                prev = masking.pairwise_mask_only(
-                    g_tree, keys.key_r, state.prev_key, silo, self.n_silos,
-                    sigma_c, 0.0, impl="perleaf")
-                masked = jax.tree.map(
-                    lambda m, p: m - lam_gate * p.astype(m.dtype), masked, prev)
-            return masked
         # the barrier keeps pack's writes out of the kernel's operand fusion:
         # fused, the TPU compile of the barrier tier's step takes ~6x
         # longer (65 s against 11 s for two qwen2.5-3b layers on 4 chips)
@@ -439,7 +379,7 @@ class DPPipeline:
             masking._raw(keys.key_r),
             masking._raw(keys.key_xi), state.prev_key, silo, self.n_silos,
             sigma_c, priv.mask_scale * sigma_c * gate, lam_gate,
-            use_pairwise=True, use_prev=use_prev, impl=self.policy.inner,
+            use_pairwise=True, use_prev=use_prev,
             nxt=self.next_active(silo, active),
             noise_scale=s if static else s * gate,
             prev_noise_scale=s_prev, xi=xi, xp=xp)
@@ -447,7 +387,7 @@ class DPPipeline:
     @scoped("dp.unpack")
     def finalize(self, agg):
         """Aggregated contribution -> fp32 gradient pytree (unpacks packed
-        buffers; perleaf aggregates are already trees)."""
+        buffers; the admin and none constructions are already trees)."""
         if isinstance(agg, jax.Array) and agg.ndim == 1:
             return flatbuf.unpack(self.layout, agg, dtype=jnp.float32)
         return jax.tree.map(lambda x: x.astype(jnp.float32), agg)
@@ -481,24 +421,22 @@ class DPPipeline:
         lam_gates = priv.noise_lambda * hp * gates * pa_gates
         return fused_ops.noise_batch_packed(
             g_sum, kx, state.prev_key, noise_scales, lam_gates, s_prev,
-            use_prev=use_prev, impl=self.policy.inner)
+            use_prev=use_prev)
 
     @scoped("dp.noise")
     def corrected_noise_tree(self, g_sum_tree, keys: BarrierKeys,
-                             state: NoiseState, bound, active):
-        """Tree-level corrected noise for the central tiers. Packed policy
-        routes through :meth:`corrected_noise_packed`; perleaf keeps the
-        sharding-preserving per-leaf jax.random construction (one stream at
-        full sigma_c — the aggregate noise std is k-independent, so elastic
-        participation needs no per-stream bookkeeping there). Admin mode
-        regenerates the exact xi the O(n*P) mask set telescopes to."""
+                             state: NoiseState, bound):
+        """Tree-level corrected noise for the silo-serial scan tier, whose
+        accumulator is FSDP-sharded: packing it would gather the whole
+        parameter buffer onto every device, so this draws the per-leaf
+        jax.random family of ``noise_correction.corrected_noise`` (one stream
+        at full sigma_c — the aggregate noise std is k-independent, so
+        elastic participation needs no per-stream bookkeeping). It is a
+        different family from the per-silo packed streams the other three
+        tiers draw. Admin mode regenerates the exact xi the O(n*P) mask set
+        telescopes to."""
         if self.priv.mask_mode == "admin":
             return self.admin_noise_tree(g_sum_tree, keys, state, bound)
-        if self.policy.mode == "packed":
-            packed = flatbuf.pack(self.layout, g_sum_tree)
-            noisy = self.corrected_noise_packed(packed, keys, state, bound,
-                                                active)
-            return flatbuf.unpack(self.layout, noisy, dtype=jnp.float32)
         sigma_c = self.priv.sigma * jnp.asarray(bound, jnp.float32)
         noise, _ = noise_correction.corrected_noise(
             g_sum_tree, keys.key_xi, state, sigma_c, self.priv.noise_lambda)

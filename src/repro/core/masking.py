@@ -8,8 +8,8 @@ Two constructions, numerically interchangeable in aggregate:
 * ``pairwise`` (beyond-paper, DESIGN.md §2): m_i = B(r_i - r_{(i+1) mod n})
   + xi_i with xi_i ~ N(0, (sigma*C)^2/n I), all streams derived from 32-byte
   per-step keys. Telescoping gives sum_i m_i = xi exactly; each silo only
-  needs its subkeys. The fused kernel (kernels/zsmask) regenerates masks in
-  VMEM so they never touch HBM.
+  needs its subkeys. The fused kernel (kernels/dp_fused) regenerates masks
+  in VMEM so they never touch HBM.
 
 Both satisfy the paper's three properties: (1) aggregate == DP-SGD noise,
 (2) each masked gradient is marginally wide-spread noise, (3) collusion of
@@ -21,9 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dispatch import kernel_variant, REGISTRY
 from repro.kernels.dp_fused import ops as fused_ops
-from repro.kernels.zsmask import ops as zs_ops
 
 
 def _raw(key: jax.Array) -> jax.Array:
@@ -36,71 +34,23 @@ def _raw(key: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Pairwise masks (key-derived, zero distribution traffic)
 #
-# Tree-level kernel ``zsmask_tree``: the packed variant flattens the whole
-# pytree into one flat buffer (core/flatbuf) and regenerates the mask in a
-# single fused dispatch with *global packed indices* as threefry counters;
-# the per-leaf variant keeps the legacy one-dispatch-per-leaf construction
-# (leaf index folded into the keys). The two draw different — equally valid —
-# stream families, so all silos of a session must resolve to the same
-# variant; both are deterministic functions of (layout, keys, silo).
-
-TREE = "zsmask_tree"
-
-
-def _mask_tree_perleaf(grads, kr, kx, silo, n_silos, sigma_c, b_scale,
-                       impl: str = "auto"):
-    leaves, treedef = jax.tree.flatten(grads)
-    out = []
-    for i, g in enumerate(leaves):
-        # per-leaf independent streams: fold the leaf index into the keys
-        kr_i = kr + jnp.uint32(0x9E3779B9) * jnp.uint32(i + 1)
-        kx_i = kx + jnp.uint32(0x85EBCA6B) * jnp.uint32(i + 1)
-        flat = g.reshape(-1)
-        masked = zs_ops.apply_zsmask(flat, kr_i, kx_i, silo, n_silos,
-                                     sigma_c, b_scale, impl=impl)
-        out.append(masked.reshape(g.shape).astype(g.dtype))
-    return jax.tree.unflatten(treedef, out)
-
-
-@kernel_variant(TREE, "packed", priority=100,
-                auto_predicate=fused_ops.prefers_packed,
-                doc="packed flat-buffer mask: one fused dispatch per tree")
-def _mask_tree_packed(grads, kr, kx, silo, n_silos, sigma_c, b_scale):
-    return fused_ops.packed_mask_tree(grads, kr, kx, silo, n_silos, sigma_c,
-                                      b_scale)
-
-
-@kernel_variant(TREE, "perleaf", priority=50,
-                doc="per-leaf dispatch (legacy stream construction)")
-def _mask_tree_perleaf_v(grads, kr, kx, silo, n_silos, sigma_c, b_scale):
-    return _mask_tree_perleaf(grads, kr, kx, silo, n_silos, sigma_c, b_scale)
-
-
-@kernel_variant(TREE, "pallas", priority=20,
-                doc="legacy name: packed engine, Pallas inner kernel")
-def _mask_tree_pallas(grads, kr, kx, silo, n_silos, sigma_c, b_scale):
-    return fused_ops.packed_mask_tree(grads, kr, kx, silo, n_silos, sigma_c,
-                                      b_scale, impl="pallas")
-
-
-@kernel_variant(TREE, "jnp", priority=10,
-                doc="legacy name: per-leaf jnp reference")
-def _mask_tree_jnp(grads, kr, kx, silo, n_silos, sigma_c, b_scale):
-    return _mask_tree_perleaf(grads, kr, kx, silo, n_silos, sigma_c, b_scale,
-                              impl="jnp")
+# The whole pytree is flattened into one flat buffer (core/flatbuf) and the
+# mask regenerated in a single fused dispatch with *global packed indices*
+# as threefry counters: the same streams the barrier and wire tiers' fused
+# clip+mask kernel draws.
 
 
 def pairwise_mask_tree(grads, key_r, key_xi, silo, n_silos: int, sigma_c,
                        b_scale: float, impl: str = "auto"):
     """Apply m_silo to every leaf of ``grads``.
-    silo may be a traced scalar (lax.axis_index); keys are per-step."""
-    return REGISTRY.dispatch(TREE, impl, fused_ops.tree_ctx(grads), grads,
-                             _raw(key_r), _raw(key_xi), silo, n_silos,
-                             sigma_c, b_scale)
+    silo may be a traced scalar (lax.axis_index); keys are per-step.
+    ``impl`` picks the packed kernel's inner variant."""
+    return fused_ops.packed_mask_tree(grads, _raw(key_r), _raw(key_xi), silo,
+                                      n_silos, sigma_c, b_scale, impl=impl)
 
 
 def pairwise_mask_only(shapes_tree, key_r, key_xi, silo, n_silos: int,
-                       sigma_c, b_scale: float, impl: str = "jnp"):
+                       sigma_c, b_scale: float, impl: str = "auto"):
     zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32), shapes_tree)
     return pairwise_mask_tree(zeros, key_r, key_xi, silo, n_silos, sigma_c,
                               b_scale, impl=impl)

@@ -114,8 +114,7 @@ def _shared_pipe_fn(pipe: DPPipeline, has_prev_active: bool,
     redraw are the same jit's output), so speculative==serial bit-identity
     holds by construction rather than by hoping two different XLA graphs
     fuse identically."""
-    key = (pipe.priv, pipe.layout, pipe.n_silos, pipe.policy,
-           has_prev_active, ext)
+    key = (pipe.priv, pipe.layout, pipe.n_silos, has_prev_active, ext)
     with _PIPE_CACHE_LOCK:
         for k, fn in _PIPE_CACHE:
             if k == key:
@@ -418,15 +417,15 @@ class DataHandler(Component):
         ONE jitted dispatch, shared by every handler of the session (the
         silo index is a traced argument — see ``_shared_pipe_fn``): the
         per-round protocol cost is the codec + channel crypto, not hundreds
-        of eager op dispatches or n XLA compiles. The admin-mask and perleaf
-        constructions keep the eager path — they rely on concrete
-        participation sets (single-row reconstruction / full-ring guard).
+        of eager op dispatches or n XLA compiles. The admin-mask
+        construction keeps the eager path — its single-row reconstruction
+        relies on a concrete participation set.
 
         On the packed pairwise path the xi/xp noise streams enter as
         ARGUMENTS (``_round_streams``) rather than being drawn in-graph, so
         the speculative scheduler can reuse round-t's xi as round-(t+1)'s
         xp without any cross-graph bitwise exposure."""
-        if pipe.priv.mask_mode == "admin" or pipe.policy.mode != "packed":
+        if pipe.priv.mask_mode == "admin":
             norm = pipe.norm_tree(grads)
             scale = pipe.clip_scale(norm, clip_bound)
             return pipe.silo_contribution(grads, self.silo_idx, scale,
@@ -475,7 +474,7 @@ class DataHandler(Component):
         # untrusted model-owner code inside the sandbox (R1/R2)
         loss, grads = self.sandbox.run(grad_fn, params, self.data)
         pipe = DPPipeline(priv, flatbuf.layout_of(grads), n_silos)
-        if priv.mask_mode == "pairwise" and pipe.policy.mode == "packed":
+        if priv.mask_mode == "pairwise":
             # remembered for prefetch_round: next round's stream needs this
             # round's layout/engine config (which the driver doesn't hold)
             self._spec_pipe = pipe
@@ -493,7 +492,7 @@ class DataHandler(Component):
             # contiguous memoryview into the channel, no tree re-traversal
             if isinstance(contrib, jax.Array) and contrib.ndim == 1:
                 buf = np.asarray(contrib)
-            else:  # perleaf/admin/none constructions yield trees
+            else:  # admin/none constructions yield trees
                 buf = wire.pack_np(pipe.layout, pipe.finalize(contrib))
             payload = wire.encode_update(pipe.layout, buf, float(loss),
                                          float(norm))

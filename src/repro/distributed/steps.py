@@ -11,7 +11,8 @@ mesh-placement shims around its stages:
     sharding. Production path.
 ``silo_mode='scan'``    — silo-serial fused path (100B-scale): a lax.scan
     accumulates clipped silo grads into an fsdp-sharded fp32 buffer; the
-    engine's ``corrected_noise_tree`` stage runs on the accumulator.
+    engine's ``corrected_noise_tree`` stage draws per-leaf noise on the
+    accumulator (the one tier that does not run the packed engine).
 ``sync_path='barrier'`` — paper-faithful wire protocol: jax.shard_map manual
     over the silo axes (pod, data), model/TP axis left auto. Each silo emits
     the engine's ``silo_contribution`` (clip + zero-sum DP-mask + its noise
@@ -38,7 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MeshConfig, PrivacyConfig, RunConfig
 from repro.core import barrier as barrier_mod
-from repro.core import dp_pipeline, flatbuf
+from repro.core import flatbuf
 from repro.core.dp_pipeline import DPPipeline
 from repro.core.noise_correction import NoiseState, init_state as init_noise_state
 from repro.distributed.sharding_rules import (constrain as constrain_logical,
@@ -135,19 +136,8 @@ def _fused_grads(model: Model, priv: PrivacyConfig, params, batch, n_silos,
     # instead of a per-leaf sumsq chain
     norms = pipe.norms(g_packed)
 
-    if priv.enabled and pipe.policy.mode == "perleaf":
-        # legacy per-leaf noise family (force_impl / REPRO_KERNEL_IMPL):
-        # aggregate packed, then the tree-level noise stage
-        bound = pipe.dynamic_bound(norms, active, clip_key, clip_bound)
-        g_sum = pipe.masked_aggregate(g_packed,
-                                      pipe.clip_scales(norms, bound, active))
-        g_tree = flatbuf.unpack(layout, g_sum, dtype=jnp.float32)
-        noisy = pipe.corrected_noise_tree(g_tree, keys, noise_state, bound,
-                                          active)
-        new_ns = pipe.advance_state(keys, noise_state, active)
-    else:
-        noisy, new_ns, bound = pipe.run_central(
-            g_packed, norms, keys, noise_state, clip_bound, clip_key, active)
+    noisy, new_ns, bound = pipe.run_central(
+        g_packed, norms, keys, noise_state, clip_bound, clip_key, active)
     gates = active.astype(losses.dtype)
     loss = jnp.sum(losses * gates) / pipe.active_count(active)
     return noisy, loss, norms, new_ns, bound
@@ -162,10 +152,9 @@ def _fused_grads_scan(model: Model, priv: PrivacyConfig, params, batch,
     the carried bound C_t (dynamic clipping is stale-by-one — the standard
     production DP-SGD quantile scheme) and its participation gate, and
     accumulated into a single fsdp-sharded fp32 buffer. The engine's
-    ``corrected_noise_tree`` stage runs on the accumulator — per-leaf policy
-    by default, which keeps the FSDP sharding (the packed engine would
-    gather the full parameter buffer onto every device;
-    ``REPRO_KERNEL_IMPL=dp_noise_tree=packed`` overrides if wanted)."""
+    ``corrected_noise_tree`` stage draws per-leaf noise on the accumulator,
+    which keeps the FSDP sharding: the packed engine of the other tiers
+    would gather the full parameter buffer onto every device."""
     silo_batches = _reshape_to_silos(batch, n_silos)
     # inner batch dim stays sharded over the silo axes (the scan consumes dim0)
     silo_batches = {
@@ -173,8 +162,7 @@ def _fused_grads_scan(model: Model, priv: PrivacyConfig, params, batch,
             else constrain_tree(v, (None, "batch") + (None,) * (v.ndim - 2)))
         for k, v in silo_batches.items()}
 
-    pipe = DPPipeline(priv, flatbuf.layout_of(params), n_silos,
-                      policy="perleaf")
+    pipe = DPPipeline(priv, flatbuf.layout_of(params), n_silos)
     active = _active_or_full(active, pipe)
     param_pspecs = params_pspecs(params)
 
@@ -210,7 +198,7 @@ def _fused_grads_scan(model: Model, priv: PrivacyConfig, params, batch,
 
     if priv.enabled:
         noisy = pipe.corrected_noise_tree(g_sum, keys, noise_state,
-                                          clip_bound, active)
+                                          clip_bound)
         new_ns = pipe.advance_state(keys, noise_state, active)
     else:
         noisy, new_ns = g_sum, noise_state
@@ -233,9 +221,6 @@ def _barrier_grads(model: Model, priv: PrivacyConfig, mesh_cfg: MeshConfig,
     n_silos = mesh_cfg.n_silos
     silo_axes = mesh_cfg.silo_axes
     pipe = DPPipeline(priv, flatbuf.layout_of(params), n_silos)
-    if pipe.policy.mode == "perleaf":
-        # the per-leaf mask family only supports the full static ring
-        active = None
     active_arr = _active_or_full(active, pipe)
     has_prev_active = noise_state.prev_active is not None
     prev_active_arr = noise_state.prev_active if has_prev_active \
@@ -298,26 +283,16 @@ def _barrier_grads(model: Model, priv: PrivacyConfig, mesh_cfg: MeshConfig,
 
 
 def build_train_step(model: Model, run_cfg: RunConfig, abstract_mesh=None,
-                     lr_schedule=None, elastic: bool = False):
+                     lr_schedule=None):
     """The jitted CITADEL++ train step. ``train_step(state, batch, root_key,
     active=None)``: ``active`` is the (n_silos,) bool participation set for
     this step — dropped silos contribute neither gradient, mask, noise share
-    nor divisor weight. ``elastic=True`` only validates up front that the
-    configured tier can honour a partial set (the barrier tier needs the
-    packed mask family for the active-ring construction)."""
+    nor divisor weight."""
     priv = run_cfg.privacy
     mesh_cfg = run_cfg.mesh
     opt = make_optimizer(run_cfg.optimizer)
     lr_schedule = lr_schedule or constant(run_cfg.optimizer.lr)
     n_silos = effective_n_silos(run_cfg)
-
-    if elastic and priv.enabled and priv.sync_path == "barrier":
-        policy = dp_pipeline.resolve_policy("packed", 1)
-        if policy.mode == "perleaf":
-            raise ValueError(
-                "elastic membership on the barrier tier needs the packed "
-                "mask engine (the per-leaf family only builds the full "
-                "static ring); lift the dp_noise_tree=perleaf override")
 
     def train_step(state: TrainState, batch, root_key, active=None):
         keys = barrier_mod.step_keys(root_key, state.step)

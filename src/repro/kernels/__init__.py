@@ -2,9 +2,8 @@
 
 Importing this package registers every kernel variant (the seven ops modules)
 with :data:`repro.kernels.dispatch.REGISTRY`, so introspection
-(``available_impls``) sees the full table. Selection overrides: the
-``force_impl`` context manager and the ``REPRO_KERNEL_IMPL`` env var — see
-``dispatch.py`` for the precedence rules.
+(``available_impls``) sees the full table. Selection override: the
+``force_impl`` context manager — see ``dispatch.py``.
 """
 from repro.kernels.dispatch import (REGISTRY, available_impls, force_impl,
                                     kernel_variant, on_tpu)
@@ -15,12 +14,6 @@ from repro.kernels.mamba2 import ops as mamba2_ops
 from repro.kernels.paged_attention import ops as paged_attention_ops
 from repro.kernels.rwkv6 import ops as rwkv6_ops
 from repro.kernels.zsmask import ops as zsmask_ops
-
-# the packed-vs-perleaf tree-level kernels (zsmask_tree, dp_noise_tree)
-# register on import of their consumer modules; the sys.modules fallback
-# makes these safe under partial initialization when core is imported first
-import repro.core.masking  # noqa: E402,F401
-import repro.core.barrier  # noqa: E402,F401
 
 __all__ = [
     "REGISTRY",

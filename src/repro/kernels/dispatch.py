@@ -24,15 +24,11 @@ Variants register with :func:`kernel_variant`:
 * ``priority`` orders candidates; highest capable+preferred wins under
   ``auto``, highest capable wins as the fallback.
 
-Selection can be overridden without touching call sites, in precedence order:
-
-1. :func:`force_impl` — a context manager (``with force_impl("jnp"): ...``),
-   optionally scoped to one kernel. Innermost wins. Thread-local, and
-   resolved at *trace* time for jitted code.
-2. ``REPRO_KERNEL_IMPL`` — environment variable, either a bare impl name
-   applied to every kernel (``REPRO_KERNEL_IMPL=jnp``) or a comma-separated
-   per-kernel list (``REPRO_KERNEL_IMPL=flash_attention=blocked,rwkv6_wkv=jnp``).
-3. The call-site ``impl=`` argument (default ``"auto"``).
+Selection can be overridden without touching call sites: :func:`force_impl`
+is a context manager (``with force_impl("jnp"): ...``), optionally scoped to
+one kernel, that outranks the call-site ``impl=`` argument (default
+``"auto"``). Innermost wins. Thread-local, and resolved at *trace* time for
+jitted code.
 
 Introspection for benchmarks and tests: :func:`available_impls`,
 :func:`KernelRegistry.kernels`, :func:`KernelRegistry.get`, and
@@ -42,13 +38,10 @@ back from Pallas).
 """
 from __future__ import annotations
 
-import os
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Any, Callable, Mapping, Optional
-
-ENV_VAR = "REPRO_KERNEL_IMPL"
 
 Ctx = Mapping[str, Any]
 Predicate = Callable[[Ctx], bool]
@@ -168,20 +161,6 @@ class KernelRegistry:
                 return impl, scope is None
         return None
 
-    @staticmethod
-    def _env_impl(kernel: str) -> Optional[tuple[str, bool]]:
-        """(impl, is_global) from REPRO_KERNEL_IMPL."""
-        raw = os.environ.get(ENV_VAR, "").strip()
-        if not raw:
-            return None
-        if "=" not in raw:  # bare name: applies to every kernel
-            return raw, True
-        for part in raw.split(","):
-            k, _, v = part.partition("=")
-            if k.strip() == kernel and v.strip():
-                return v.strip(), False
-        return None
-
     # -- selection ---------------------------------------------------------
     def resolve(self, kernel: str, impl: str = "auto",
                 ctx: Optional[Ctx] = None) -> Variant:
@@ -196,7 +175,7 @@ class KernelRegistry:
         full_ctx = dict(ctx or {})
         full_ctx.setdefault("on_tpu", on_tpu())
 
-        override = self._forced(kernel) or self._env_impl(kernel)
+        override = self._forced(kernel)
         requested = impl
         if override is not None:
             name, is_global = override

@@ -2,24 +2,17 @@
 kernel-dispatch registry.
 
 Tensor-level kernels (``dp_clip_sumsq``, ``dp_clip_accumulate``) operate on
-one (B, D) block; the tree-level kernel ``dp_clip_tree`` chooses between the
-packed flat-buffer engine (one fused ``dp_fused_clip_sum`` dispatch over the
-whole pytree — kernels/dp_fused) and the legacy per-leaf path (2 dispatches
-per leaf). ``auto`` prefers packed on TPU (where dispatch count dominates);
-override per kernel with ``REPRO_KERNEL_IMPL=dp_clip_tree=perleaf`` etc."""
+one (B, D) block. A whole pytree is clipped through one fused
+``dp_fused_clip_sum`` dispatch on its packed buffer
+(``kernels/dp_fused/ops.packed_clip_and_sum``)."""
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from repro.kernels.dispatch import kernel_variant, on_tpu, REGISTRY
 from repro.kernels.dp_clip import ref
 from repro.kernels.dp_clip.dp_clip import clip_accumulate, per_example_sumsq
-from repro.kernels.dp_fused import ops as fused_ops
 
 SUMSQ = "dp_clip_sumsq"
 ACCUM = "dp_clip_accumulate"
-TREE = "dp_clip_tree"
 
 
 def _blockable(ctx) -> bool:
@@ -62,54 +55,3 @@ def clipped_sum(g, scale, impl: str = "auto"):
     return REGISTRY.dispatch(ACCUM, impl,
                              {"B": g.shape[0], "D": g.shape[1]}, g, scale)
 
-
-# ---------------------------------------------------------------------------
-# Tree-level: packed flat-buffer engine vs legacy per-leaf dispatch
-
-
-def _clip_and_sum_perleaf(grads_tree, clip_bound, impl: str = "auto"):
-    """Per-leaf path: 2 dispatches per pytree leaf. Global per-example norm
-    combines per-leaf partial sumsq, then each leaf is scaled and reduced
-    over B."""
-    leaves = jax.tree.leaves(grads_tree)
-    B = leaves[0].shape[0]
-    flat = [g.reshape(B, -1) for g in leaves]
-    total = sum(sumsq(g, impl) for g in flat)
-    scale = ref.clip_scales(total, clip_bound)
-    summed = [clipped_sum(g, scale, impl) for g in flat]
-    out = jax.tree.unflatten(
-        jax.tree.structure(grads_tree),
-        [s.reshape(l.shape[1:]) for s, l in zip(summed, leaves)])
-    return out, jnp.sqrt(total)
-
-
-@kernel_variant(TREE, "packed", priority=100,
-                auto_predicate=fused_ops.prefers_packed,
-                doc="packed flat-buffer engine: one fused dispatch per tree")
-def _tree_packed(grads_tree, clip_bound):
-    return fused_ops.packed_clip_and_sum(grads_tree, clip_bound)
-
-
-@kernel_variant(TREE, "perleaf", priority=50,
-                doc="per-leaf dispatch (2 kernels per leaf)")
-def _tree_perleaf(grads_tree, clip_bound):
-    return _clip_and_sum_perleaf(grads_tree, clip_bound)
-
-
-@kernel_variant(TREE, "pallas", priority=20,
-                doc="legacy name: packed engine, Pallas inner kernel")
-def _tree_pallas(grads_tree, clip_bound):
-    return fused_ops.packed_clip_and_sum(grads_tree, clip_bound, impl="pallas")
-
-
-@kernel_variant(TREE, "jnp", priority=10,
-                doc="legacy name: per-leaf jnp reference")
-def _tree_jnp(grads_tree, clip_bound):
-    return _clip_and_sum_perleaf(grads_tree, clip_bound, impl="jnp")
-
-
-def clip_and_sum_tree(grads_tree, clip_bound, impl: str = "auto"):
-    """Per-example clip over a pytree of (B, ...) per-example grads, returning
-    the clipped *sum* tree (fp32 leaves) + the per-example pre-clip norms."""
-    return REGISTRY.dispatch(TREE, impl, fused_ops.tree_ctx(grads_tree),
-                             grads_tree, clip_bound)

@@ -1,10 +1,9 @@
 """Public API for the packed flat-buffer DP engine, routed through the
-kernel-dispatch registry (two tensor-level kernels: ``dp_fused_clip_sum``
-and ``dp_fused_clip_mask``) plus the pack -> kernel -> unpack tree helpers
-the core modules build on."""
+kernel-dispatch registry (three tensor-level kernels: ``dp_fused_clip_sum``,
+``dp_fused_clip_mask`` and ``dp_fused_noise_batch``) plus the pack -> kernel
+-> unpack tree helpers the core modules build on."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import flatbuf
@@ -17,20 +16,6 @@ from repro.kernels.dp_fused.dp_fused import (clip_mask_pallas,
 CLIP_SUM = "dp_fused_clip_sum"
 CLIP_MASK = "dp_fused_clip_mask"
 NOISE_BATCH = "dp_fused_noise_batch"
-
-def tree_ctx(tree):
-    return {"n_leaves": len(jax.tree.leaves(tree))}
-
-
-def prefers_packed(ctx) -> bool:
-    """auto policy for the tree-level kernels: packed wins on TPU (O(1)
-    kernel launches instead of O(leaves)); on CPU XLA fuses the per-leaf
-    path anyway and the pack/unpack copies put packed within noise of — or
-    behind — per-leaf for standalone ops, so auto stays per-leaf there.
-    The step builders request packed explicitly (they amortize one
-    pack/unpack over the whole clip+sum+noise pipeline, which measures
-    1.8-2x faster even on CPU — see benchmarks kernels/dp_pipeline_*)."""
-    return ctx["on_tpu"]
 
 
 def _divisible(d: int, block: int) -> bool:

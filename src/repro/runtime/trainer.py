@@ -125,15 +125,8 @@ class Trainer:
             self.straggler.on_escalate = lambda decision: \
                 self.membership.drop_one(self._step,
                                          telemetry=self.telemetry)
-        # budgets (like elastic mode) can shrink the participation set, so
-        # the build-time validation must fire for them too — the barrier
-        # tier's perleaf mask family would silently discard a partial set
-        # (aggregating an excluded silo the ledger stops charging)
-        partial_sets = self.tcfg.elastic or self.silo_schedule is not None \
-            or (self.accountant is not None and self.accountant.has_budgets())
         self.train_step = steps_mod.build_train_step(
-            self.model, self.run_cfg, abstract_mesh=self.mesh,
-            elastic=partial_sets)
+            self.model, self.run_cfg, abstract_mesh=self.mesh)
         self._jit_step = jax.jit(self.train_step, donate_argnums=(0,))
 
     def _active_for(self, step: int):
@@ -269,20 +262,6 @@ class Trainer:
                 # budgets carried only by the checkpoint still need a
                 # membership layer to record exclusion decisions
                 self.membership = SiloMembership(self.n_silos)
-            priv = self.run_cfg.privacy
-            if restored_ledger.has_budgets() and priv.enabled \
-                    and priv.sync_path == "barrier":
-                # the build-time guard couldn't see checkpoint-carried
-                # budgets; a perleaf barrier step would silently aggregate
-                # the full ring while the ledger stops charging excluded
-                # silos (privacy under-accounting)
-                from repro.core import dp_pipeline
-                if dp_pipeline.resolve_policy("packed", 1).mode == "perleaf":
-                    raise ValueError(
-                        "checkpoint carries per-silo budgets but the barrier "
-                        "tier resolved the perleaf mask family, which only "
-                        "builds the full static ring; lift the "
-                        "dp_noise_tree=perleaf override to enforce budgets")
             if self.membership is not None:
                 # re-apply standing exclusion decisions (the pending queue is
                 # not persisted; what matters is who is exhausted *now*)

@@ -1,13 +1,13 @@
 """Kernel-dispatch registry: registration, selection policy, overrides, and
-legacy impl-name compatibility across all five kernel packages."""
+the impl names of every kernel package's tensor-level kernels."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import REGISTRY, available_impls, force_impl
-from repro.kernels.dispatch import ENV_VAR, KernelRegistry
-from repro.kernels.dp_clip import ops as dops
+from repro.kernels.dispatch import KernelRegistry
+from repro.kernels.dp_fused import ops as fused_ops
 from repro.kernels.flash_attention import ops as fops
 from repro.kernels.mamba2 import ops as mops
 from repro.kernels.rwkv6 import ops as rops
@@ -85,35 +85,14 @@ def test_force_impl_context_manager_scoping_and_nesting():
         assert reg.resolve("k", "auto", ctx).name == "ref"
 
 
-def test_env_var_override(monkeypatch):
-    reg = _toy_registry()
-    ctx = {"n": 8, "on_tpu": False}
-    monkeypatch.setenv(ENV_VAR, "mid")  # bare name: every kernel
-    assert reg.resolve("k", "auto", ctx).name == "mid"
-    monkeypatch.setenv(ENV_VAR, "k=mid,other=ref")  # per-kernel list
-    assert reg.resolve("k", "auto", ctx).name == "mid"
-    monkeypatch.setenv(ENV_VAR, "other=mid")  # not for this kernel
-    assert reg.resolve("k", "auto", ctx).name == "ref"
-    # force_impl outranks the env var
-    monkeypatch.setenv(ENV_VAR, "mid")
-    with reg.force_impl("ref"):
-        assert reg.resolve("k", "auto", ctx).name == "ref"
-
-
-def test_global_override_with_foreign_impl_name_is_ignored(monkeypatch):
-    """A fleet-wide override naming an impl some kernel doesn't have must not
+def test_global_override_with_foreign_impl_name_is_ignored():
+    """A global override naming an impl some kernel doesn't have must not
     crash that kernel; a scoped override with a bad name must."""
     reg = _toy_registry()
     ctx = {"n": 8, "on_tpu": False}
-    monkeypatch.setenv(ENV_VAR, "blocked")  # no such impl on kernel "k"
-    assert reg.resolve("k", "auto", ctx).name == "ref"
-    assert reg.resolve("k", "mid", ctx).name == "mid"  # call-site still wins
-    monkeypatch.setenv(ENV_VAR, "k=blocked")  # scoped: explicit target, error
-    with pytest.raises(ValueError, match="unknown impl"):
-        reg.resolve("k", "auto", ctx)
-    monkeypatch.delenv(ENV_VAR)
-    with reg.force_impl("blocked"):  # global force: same tolerance
+    with reg.force_impl("blocked"):  # no such impl on kernel "k"
         assert reg.resolve("k", "auto", ctx).name == "ref"
+        assert reg.resolve("k", "mid", ctx).name == "mid"  # call site wins
     with reg.force_impl("blocked", "k"), pytest.raises(ValueError,
                                                       match="unknown impl"):
         reg.resolve("k", "auto", ctx)
@@ -145,11 +124,9 @@ def test_dispatch_calls_selected_fn():
 EXPECTED_IMPLS = {
     "dp_clip_sumsq": {"pallas", "jnp"},
     "dp_clip_accumulate": {"pallas", "jnp"},
-    "dp_clip_tree": {"packed", "perleaf", "pallas", "jnp"},
     "dp_fused_clip_sum": {"pallas", "jnp"},
     "dp_fused_clip_mask": {"pallas", "jnp"},
     "dp_fused_noise_batch": {"pallas", "jnp"},
-    "dp_noise_tree": {"packed", "perleaf", "pallas", "jnp"},
     "flash_attention": {"pallas", "blocked", "blocked_naive", "jnp"},
     "mamba2_ssd": {"pallas", "jnp", "sequential"},
     "paged_attention": {"pallas", "gather", "jnp"},
@@ -157,15 +134,14 @@ EXPECTED_IMPLS = {
     "paged_rollback": {"pallas", "jnp"},
     "rwkv6_wkv": {"pallas", "jnp", "masked", "sequential"},
     "zsmask": {"pallas", "jnp"},
-    "zsmask_tree": {"packed", "perleaf", "pallas", "jnp"},
 }
 
 
-def test_all_kernels_registered_with_legacy_impl_names():
+def test_all_tensor_kernels_registered_with_their_impl_names():
     assert set(REGISTRY.kernels()) == set(EXPECTED_IMPLS)
     for kernel, names in EXPECTED_IMPLS.items():
         assert set(available_impls(kernel)) == names, kernel
-        for name in names:  # every legacy impl string still resolves
+        for name in names:
             assert REGISTRY.get(kernel, name).name == name
 
 
@@ -244,25 +220,18 @@ def test_force_impl_reaches_kernel_call_sites():
         assert REGISTRY.resolve("zsmask", "auto", {"offset": 0}).name == "jnp"
 
 
-def test_env_override_on_real_kernels(monkeypatch):
-    monkeypatch.setenv(ENV_VAR, "flash_attention=blocked_naive")
-    assert REGISTRY.resolve("flash_attention", "auto", {"S": 128}).name \
-        == "blocked_naive"
-    # other kernels unaffected
-    assert REGISTRY.resolve("zsmask", "auto", {"offset": 0}).name \
-        in ("jnp", "pallas")
-    q, k, v = _flash_inputs()
-    ref = fops.flash_attention(q, k, v, impl="jnp")
-    np.testing.assert_allclose(np.asarray(fops.flash_attention(q, k, v)),
-                               np.asarray(ref), atol=2e-5)
-
-
 def test_dp_clip_tree_impls_agree():
+    """The packed tree clip under each forced tensor-kernel variant: the
+    Pallas kernel (interpreted off the chip) matches the jnp reference."""
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     tree = {"a": jax.random.normal(ks[0], (4, 3, 3)),
             "b": jax.random.normal(ks[1], (4, 7))}
-    s_jnp, n_jnp = dops.clip_and_sum_tree(tree, 1.0, impl="jnp")
-    s_pal, n_pal = dops.clip_and_sum_tree(tree, 1.0, impl="pallas")
+    out = {}
+    for impl in ("jnp", "pallas"):
+        with REGISTRY.record() as used, force_impl(impl):
+            out[impl] = fused_ops.packed_clip_and_sum(tree, 1.0)
+        assert used == {"dp_fused_clip_sum": {impl}}
+    (s_jnp, n_jnp), (s_pal, n_pal) = out["jnp"], out["pallas"]
     np.testing.assert_allclose(np.asarray(n_jnp), np.asarray(n_pal), rtol=1e-4)
     for a, b in zip(jax.tree.leaves(s_jnp), jax.tree.leaves(s_pal)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -5,7 +5,8 @@ wiring.
 
 The four execution tiers:
   * fused  — vmap shim over ``DPPipeline.run_central`` (distributed/steps.py)
-  * scan   — silo-serial shim over the engine's tree stages
+  * scan   — silo-serial shim over the engine's tree stages (per-leaf noise
+             on its FSDP-sharded accumulator, a different stream family)
   * barrier— shard_map shim psumming ``silo_contribution`` (subprocess: needs
              a multi-device mesh)
   * wire   — TEE component protocol invoking the same stages per message
@@ -25,10 +26,9 @@ from repro.configs.paper_models import MNIST_MLP3
 from repro.core import barrier as barrier_mod
 from repro.core import flatbuf
 from repro.core.dp_pipeline import DPPipeline, reduce_contributions
-from repro.core.noise_correction import NoiseState, init_state
+from repro.core.noise_correction import NoiseState, corrected_noise, init_state
 from repro.data.synthetic import synthetic_mnist
 from repro.distributed import steps as steps_mod
-from repro.kernels import force_impl
 from repro.models.registry import Model
 from repro.models.small import build_small_model
 
@@ -90,6 +90,31 @@ def manual_aggregate(model, params, batch, keys, active, sigma_c=SIGMA,
     return flatbuf.unpack(layout, total, dtype=jnp.float32)
 
 
+def manual_scan_aggregate(model, params, batch, keys, active, state, lam,
+                          sigma_c=SIGMA):
+    """Ground truth for the scan tier: the active silos' clipped grads plus
+    the per-leaf corrected noise it draws on its accumulator."""
+    from repro.core import clipping
+
+    total = None
+    for i in range(N):
+        if not bool(active[i]):
+            continue
+        sl = {k: v[i * 8:(i + 1) * 8] for k, v in batch.items()}
+        g, _ = clipping.clip_tree(jax.grad(model.loss)(params, sl), 1.0)
+        total = g if total is None else jax.tree.map(jnp.add, total, g)
+    noise, _ = corrected_noise(total, keys.key_xi, state, sigma_c, lam)
+    return jax.tree.map(lambda g, n: g.astype(jnp.float32) + n, total, noise)
+
+
+def primed_state():
+    """A step-t state whose step t-1 had every silo contributing, so the
+    lambda-correction terms are live."""
+    return NoiseState(prev_key=jnp.array([7, 8], jnp.uint32),
+                      has_prev=jnp.ones((), jnp.bool_),
+                      prev_active=jnp.ones((N,), jnp.bool_))
+
+
 def max_err(a_tree, b_tree):
     return max(float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)).max())
                for a, b in zip(jax.tree.leaves(a_tree), jax.tree.leaves(b_tree)))
@@ -100,19 +125,21 @@ def max_err(a_tree, b_tree):
 # on a real 4-device mesh below)
 
 
-def test_fused_scan_wire_parity_all_active():
-    """All tiers resolve the same packed engine -> the same aggregate."""
-    model, priv, params, batch, keys = setup()
-    ns = init_state(jax.random.PRNGKey(0), n_silos=N)
+@pytest.mark.parametrize("lam", [0.0, 0.7])
+def test_fused_scan_wire_parity_all_active(lam):
+    """The fused and wire tiers run the same packed engine -> the same
+    aggregate; the scan tier adds its per-leaf noise to the same clipped
+    sum, with the lambda-correction live when ``lam`` > 0."""
+    model, priv, params, batch, keys = setup(lam=lam)
+    ns = primed_state()
 
     fused, loss_f, _, _, _ = steps_mod._fused_grads(
         model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
         keys.key_clip)
 
-    with force_impl("packed", "dp_noise_tree"):
-        scan, loss_s, _, _, _ = steps_mod._fused_grads_scan(
-            model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
-            keys.key_clip)
+    scan, loss_s, _, _, _ = steps_mod._fused_grads_scan(
+        model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
+        keys.key_clip)
 
     # wire tier: per-silo silo_contribution + updater-order reduce
     layout = flatbuf.layout_of(params)
@@ -127,10 +154,14 @@ def test_fused_scan_wire_parity_all_active():
             g, i, scale, active, keys, ns, 1.0)))
     wire = reduce_contributions(contribs)
 
-    manual = manual_aggregate(model, params, batch, keys, np.ones(N, bool))
+    all_active = np.ones(N, bool)
+    manual = manual_aggregate(model, params, batch, keys, all_active,
+                              state=ns, lam=lam)
     assert max_err(fused, manual) < 2e-4
-    assert max_err(scan, manual) < 2e-4
     assert max_err(wire, manual) < 2e-4
+    manual_scan = manual_scan_aggregate(model, params, batch, keys,
+                                        all_active, ns, lam)
+    assert max_err(scan, manual_scan) < 2e-4
     assert max_err(fused, wire) < 2e-4
     np.testing.assert_allclose(float(loss_f), float(loss_s), rtol=1e-5)
 
@@ -220,26 +251,30 @@ def test_parity_with_dynamic_clipping_and_correction():
 # dropout: k < n active silos
 
 
-def test_dropout_aggregate_equals_k_silo_ground_truth():
+@pytest.mark.parametrize("lam", [0.0, 0.7])
+def test_dropout_aggregate_equals_k_silo_ground_truth(lam):
     """With active = [1,0,1,1] the aggregate must equal the 3-silo ground
     truth: dropped silos contribute no gradient, no mask, no noise share, and
-    the noise std re-normalizes to exactly sigma*C."""
-    model, priv, params, batch, keys = setup()
-    ns = init_state(jax.random.PRNGKey(0), n_silos=N)
+    the noise std re-normalizes to exactly sigma*C. The scan tier's per-leaf
+    noise is one stream at full sigma*C whatever the participation set."""
+    model, priv, params, batch, keys = setup(lam=lam)
+    ns = primed_state()
     active_np = np.array([True, False, True, True])
     active = jnp.asarray(active_np)
 
     fused, loss, _, _, _ = steps_mod._fused_grads(
         model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
         keys.key_clip, active=active)
-    manual = manual_aggregate(model, params, batch, keys, active_np)
+    manual = manual_aggregate(model, params, batch, keys, active_np,
+                              state=ns, lam=lam)
     assert max_err(fused, manual) < 2e-4
 
-    with force_impl("packed", "dp_noise_tree"):
-        scan, _, _, _, _ = steps_mod._fused_grads_scan(
-            model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
-            keys.key_clip, active=active)
-    assert max_err(scan, manual) < 2e-4
+    scan, _, _, _, _ = steps_mod._fused_grads_scan(
+        model, priv, params, batch, N, keys, ns, jnp.float32(1.0),
+        keys.key_clip, active=active)
+    manual_scan = manual_scan_aggregate(model, params, batch, keys,
+                                        active_np, ns, lam)
+    assert max_err(scan, manual_scan) < 2e-4
 
 
 def test_dropout_masks_still_sum_to_zero():

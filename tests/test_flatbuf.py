@@ -10,7 +10,7 @@ from repro.configs.base import PrivacyConfig
 from repro.core import barrier as barrier_mod
 from repro.core import flatbuf, masking
 from repro.core.noise_correction import init_state
-from repro.kernels.dp_clip import ops as dops
+from repro.kernels.dp_clip import ref as dref
 from repro.kernels.dp_fused import ops as fops
 from repro.kernels.dp_fused import ref as fref
 
@@ -102,11 +102,18 @@ def test_hypothesis_roundtrip_property():
 
 
 def test_clip_and_sum_packed_matches_perleaf():
+    """The packed tree clip against the per-example reference built leaf by
+    leaf from the kernels/dp_clip oracles."""
     t = mixed_tree(jax.random.PRNGKey(5), B=8)
-    s_pl, n_pl = dops.clip_and_sum_tree(t, 0.7, impl="perleaf")
-    s_pk, n_pk = dops.clip_and_sum_tree(t, 0.7, impl="packed")
+    flat = [g.reshape(8, -1) for g in jax.tree.leaves(t)]
+    total = sum(dref.per_example_sumsq_ref(g) for g in flat)
+    scale = dref.clip_scales(total, 0.7)
+    s_pl = [dref.clip_accumulate_ref(g, scale) for g in flat]
+    n_pl = jnp.sqrt(total)
+    s_pk, n_pk = fops.packed_clip_and_sum(t, 0.7)
     np.testing.assert_allclose(np.asarray(n_pk), np.asarray(n_pl), rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(s_pk), jax.tree.leaves(s_pl)):
+    for a, b in zip(jax.tree.leaves(s_pk), s_pl):
+        b = b.reshape(a.shape)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
 
@@ -235,11 +242,35 @@ def test_packed_masks_telescope_to_aggregate_noise():
     total = None
     for i in range(n):
         m = masking.pairwise_mask_tree(zeros, KEY_R, KEY_XI, jnp.int32(i), n,
-                                       sigma_c, b, impl="packed")
+                                       sigma_c, b)
         total = m if total is None else jax.tree.map(jnp.add, total, m)
     expect = barrier_mod.aggregate_noise_from_streams(t, keys, n, sigma_c)
     for a, b_ in zip(jax.tree.leaves(total), jax.tree.leaves(expect)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-3)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_pairwise_mask_tree_draws_the_kernel_streams(impl):
+    """Each silo's tree mask is the fused clip+mask kernel's own stream
+    family over global packed indices (what the barrier and wire tiers
+    emit), on every platform and through either inner kernel."""
+    from repro.kernels import REGISTRY
+
+    n, sigma_c, b = 3, 2.0, 8.0
+    t = {"w": jnp.zeros((40, 50), jnp.float32),
+         "m": jnp.zeros((2, 7, 9), jnp.float32)}
+    lay = flatbuf.layout_of(t)
+    for i in range(n):
+        with REGISTRY.record() as used:
+            m = masking.pairwise_mask_tree(t, KEY_R, KEY_XI, jnp.int32(i), n,
+                                           sigma_c, b, impl=impl)
+        assert used == {"dp_fused_clip_mask": {impl}}
+        expect = flatbuf.unpack(lay, fref.clip_mask_ref(
+            jnp.zeros((lay.total,), jnp.float32), 1.0, KEY_R, KEY_XI, KEY_XI,
+            jnp.int32(i), n, sigma_c, b, 0.0, use_prev=False))
+        for a, e in zip(jax.tree.leaves(m), jax.tree.leaves(expect)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                       atol=1e-5)
 
 
 def test_packed_aggregate_noise_scale():
@@ -248,7 +279,7 @@ def test_packed_aggregate_noise_scale():
     total = None
     for i in range(n):
         m = masking.pairwise_mask_tree(big, KEY_R, KEY_XI, jnp.int32(i), n,
-                                       sigma_c, 8.0, impl="packed")
+                                       sigma_c, 8.0)
         total = m if total is None else jax.tree.map(jnp.add, total, m)
     std = float(np.std(np.asarray(total["w"])))
     assert abs(std - sigma_c) / sigma_c < 0.08
@@ -281,15 +312,14 @@ def test_fused_noise_packed_first_step_has_no_correction():
     keys = barrier_mod.BarrierKeys(KEY_R, KEY_XI, KEY_P)
     t = {"w": jnp.zeros((2048,), jnp.float32)}
     fresh = init_state(jax.random.PRNGKey(0))  # has_prev=False
-    noisy, new_state = barrier_mod.fused_noise(t, priv, keys, fresh, 1.0,
-                                               impl="packed")
-    # gate=0 -> plain xi_t at scale sigma*C, from the single packed stream
     lay = flatbuf.layout_of(t)
+    noisy, new_state = barrier_mod.fused_noise_packed(
+        flatbuf.pack(lay, t), priv, keys, fresh, 1.0)
+    # gate=0 -> plain xi_t at scale sigma*C, from the single packed stream
     expect = fref.clip_mask_ref(
         jnp.zeros((lay.total,), jnp.float32), 1.0, KEY_XI, KEY_XI, KEY_P,
         jnp.int32(0), 1, 1.0, 0.0, 0.0, use_pairwise=False, use_prev=False)
-    np.testing.assert_allclose(np.asarray(noisy["w"]),
-                               np.asarray(flatbuf.unpack(lay, expect)["w"]),
+    np.testing.assert_allclose(np.asarray(noisy), np.asarray(expect),
                                atol=1e-6)
     assert bool(new_state.has_prev)
     np.testing.assert_array_equal(np.asarray(new_state.prev_key),
@@ -301,17 +331,17 @@ def test_fused_noise_packed_regenerates_prev_from_key():
     path (the O(1)-state noise correction, paper §4.4)."""
     priv = PrivacyConfig(enabled=True, sigma=2.0, clip_bound=1.0,
                          noise_lambda=0.7)
-    t = {"w": jnp.zeros((4096,), jnp.float32)}
+    g = jnp.zeros((4096,), jnp.float32)
     k1 = barrier_mod.BarrierKeys(KEY_R, KEY_XI, KEY_P)
     k2 = barrier_mod.BarrierKeys(KEY_R, KEY_P, KEY_XI)  # step-2 noise key
     s0 = init_state(jax.random.PRNGKey(0))
-    xi1, s1 = barrier_mod.fused_noise(t, priv, k1, s0, 1.0, impl="packed")
-    n2, _ = barrier_mod.fused_noise(t, priv, k2, s1, 1.0, impl="packed")
+    xi1, s1 = barrier_mod.fused_noise_packed(g, priv, k1, s0, 1.0)
+    n2, _ = barrier_mod.fused_noise_packed(g, priv, k2, s1, 1.0)
     lam0 = PrivacyConfig(enabled=True, sigma=2.0, clip_bound=1.0,
                          noise_lambda=0.0)
-    xi2, _ = barrier_mod.fused_noise(t, lam0, k2, s0, 1.0, impl="packed")
-    expect = np.asarray(xi2["w"]) - 0.7 * np.asarray(xi1["w"])
-    np.testing.assert_allclose(np.asarray(n2["w"]), expect, atol=1e-5)
+    xi2, _ = barrier_mod.fused_noise_packed(g, lam0, k2, s0, 1.0)
+    expect = np.asarray(xi2) - 0.7 * np.asarray(xi1)
+    np.testing.assert_allclose(np.asarray(n2), expect, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -330,8 +360,8 @@ def test_per_example_clipped_grad_packed_matches_manual():
              "y": jax.random.normal(key, (8, 1))}
     C = 0.5
     summed, norms, _ = jax.jit(
-        lambda pp, bb: clipping.per_example_clipped_grad(loss, pp, bb, C,
-                                                         impl="packed"))(p, batch)
+        lambda pp, bb: clipping.per_example_clipped_grad(loss, pp, bb, C))(
+            p, batch)
     manual = np.zeros((4, 1), np.float32)
     for i in range(8):
         ex = {k: v[i:i + 1] for k, v in batch.items()}

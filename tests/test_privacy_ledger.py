@@ -171,24 +171,106 @@ def test_trainer_excludes_exhausted_silo_next_step():
     assert per_silo[1] < per_silo[0]
 
 
-def test_barrier_perleaf_with_budgets_rejected():
-    """Budgets shrink participation sets, which the barrier tier's perleaf
-    mask family can't honor (it builds the full static ring) — the trainer
-    must refuse at build time instead of silently under-accounting."""
-    from repro.kernels import force_impl
+BARRIER_RESTORE_SCRIPT = r"""
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs.base import (MeshConfig, OptimizerConfig, PrivacyConfig,
+                                RunConfig, SHAPES)
+from repro.configs.paper_models import MNIST_MLP3
+from repro.core import barrier as barrier_mod, clipping, flatbuf
+from repro.data.synthetic import synthetic_mnist
+from repro.distributed import steps as steps_mod
+from repro.kernels.dp_fused.ref import _stream
+from repro.models.registry import Model
+from repro.models.small import build_small_model
+from repro.runtime.trainer import Trainer, TrainerConfig
 
-    sm = build_small_model(MNIST_MLP3)
-    model = as_model(sm)
-    rc = RunConfig(
-        model=None, shape=SHAPES["train_4k"], mesh=MeshConfig((1,), ("data",)),
-        privacy=PrivacyConfig(enabled=True, sigma=0.5, clip_bound=1.0,
-                              sync_path="barrier"),
-        optimizer=OptimizerConfig(name="sgd", lr=0.1))
-    with force_impl("perleaf", "dp_noise_tree"):
-        with pytest.raises(ValueError, match="perleaf"):
-            Trainer(model, rc, TrainerConfig(total_steps=1, log_every=0,
-                                             silo_epsilon_budget=1.0),
-                    lambda: {})
+N, SIGMA, LAM = 4, 0.5, 0.7
+ckpt = sys.argv[1]
+sm = build_small_model(MNIST_MLP3)
+model = Model(cfg=None, init=sm.init, loss=sm.loss, init_cache=None,
+              prefill=None, decode_step=None)
+rc = RunConfig(model=None, shape=SHAPES["train_4k"],
+               mesh=MeshConfig((N,), ("data",)),
+               privacy=PrivacyConfig(enabled=True, sigma=SIGMA, clip_bound=1.0,
+                                     noise_lambda=LAM, sync_path="barrier"),
+               optimizer=OptimizerConfig(name="sgd", lr=1.0))
+train, _ = synthetic_mnist(n_train=128, n_test=16)
+batch = {"x": jnp.asarray(train.x[:32]), "y": jnp.asarray(train.y[:32])}
+root = jax.random.PRNGKey(1)
+
+
+def trainer(total_steps, budgets=None):
+    return Trainer(model, rc, TrainerConfig(
+        total_steps=total_steps, log_every=0, checkpoint_dir=ckpt,
+        checkpoint_every=1, silo_budgets=budgets), lambda: batch)
+
+
+# step 0 with every silo; silo 1's budget is spent by it
+tr1 = trainer(1, budgets={1: 0.001})
+state1, _ = tr1.fit(steps_mod.init_train_state(model, rc,
+                                               jax.random.PRNGKey(0)), root)
+state1 = jax.device_get(state1)
+assert tr1.accountant.silo_exhausted(1)
+
+# resumed without budget flags: the checkpoint's budgets exclude silo 1
+tr2 = trainer(2)
+state2, step = tr2.fit(steps_mod.init_train_state(model, rc,
+                                                  jax.random.PRNGKey(0)), root)
+assert step == 2
+tr2._flush_metrics()
+assert [m["n_contributions"] for m in tr2.metrics_log] == [4.0, 3.0]
+assert tr2.membership.excluded == (1,)
+
+# the step-1 aggregate, read back through SGD at lr 1 over 3 contributors
+layout = flatbuf.layout_of(state1.params)
+agg = (flatbuf.pack(layout, state1.params)
+       - flatbuf.pack(layout, jax.device_get(state2.params))) * 3.0
+active = [True, False, True, True]
+keys = barrier_mod.step_keys(root, jnp.asarray(1, jnp.int32))
+ns = state1.noise_state
+assert bool(ns.has_prev) and bool(np.all(ns.prev_active))
+idx = jnp.arange(layout.total, dtype=jnp.uint32)
+manual = jnp.zeros((layout.total,), jnp.float32)
+for i in range(N):
+    if not active[i]:
+        continue
+    sl = {k: v[i * 8:(i + 1) * 8] for k, v in batch.items()}
+    g, _ = clipping.clip_tree(jax.grad(model.loss)(state1.params, sl), 1.0)
+    manual = manual + flatbuf.pack(layout, g)
+    manual = manual + SIGMA / np.sqrt(3.0) * _stream(keys.key_xi, idx,
+                                                     jnp.uint32(i))
+    manual = manual - LAM * SIGMA / np.sqrt(4.0) * _stream(
+        ns.prev_key, idx, jnp.uint32(i))
+# compared leaf by leaf: the streams also cover the layout's padding
+err = max(float(jnp.abs(a - b).max()) for a, b in zip(
+    jax.tree.leaves(flatbuf.unpack(layout, agg)),
+    jax.tree.leaves(flatbuf.unpack(layout, manual))))
+print("restored barrier step vs active-ring aggregate max err:", err)
+assert err < 1e-3, err
+print("OK")
+"""
+
+
+@pytest.mark.slow
+def test_barrier_restore_with_budgets_steps_partial_set(tmp_path):
+    """A barrier-tier Trainer resumed from a checkpoint whose budgets exclude
+    a silo steps over the partial participation set: its aggregate is the
+    active ring's clipped grads plus their noise and correction shares
+    (4 host devices, so in a subprocess)."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
+           "HOME": str(tmp_path), "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run(
+        [sys.executable, "-c", BARRIER_RESTORE_SCRIPT, str(tmp_path / "ck")],
+        capture_output=True, text=True, timeout=560, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+    assert "OK" in r.stdout
 
 
 def test_trainer_stops_when_all_budgets_spent():

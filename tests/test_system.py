@@ -58,11 +58,11 @@ def test_fused_path_equals_manual_dp_sgd():
 
 
 def test_silo_scan_mode_matches_vmap_mode():
-    """The memory-optimal silo-serial path computes the same aggregate as the
-    vmap path (same clipping, same noise keys). The scan path defaults to
-    per-leaf noise (it keeps the FSDP-sharded accumulator), so the vmap path
-    is pinned to the same noise construction for the comparison."""
-    from repro.kernels import force_impl
+    """The memory-optimal silo-serial path computes the same clipped
+    aggregate as the vmap path. The scan path draws per-leaf noise (it keeps
+    the FSDP-sharded accumulator), so its output less that noise, drawn on
+    the same keys, equals the vmap path's noiseless (sigma 0) output."""
+    from repro.core.noise_correction import corrected_noise
 
     sm = build_small_model(MNIST_MLP3)
     model = as_model(sm)
@@ -71,19 +71,21 @@ def test_silo_scan_mode_matches_vmap_mode():
     from repro.core import barrier as barrier_mod
     keys = barrier_mod.step_keys(jax.random.PRNGKey(9), jnp.zeros((), jnp.int32))
     outs = {}
-    for mode in ("vmap", "scan"):
-        priv = PrivacyConfig(enabled=True, sigma=0.25, clip_bound=1.0,
+    for mode, sigma in (("vmap", 0.0), ("scan", 0.25)):
+        priv = PrivacyConfig(enabled=True, sigma=sigma, clip_bound=1.0,
                              clip_mode="per_silo", n_silos=4, silo_mode=mode)
         rc = RunConfig(model=None, shape=SHAPES["train_4k"],
                        mesh=MeshConfig((1,), ("data",)), privacy=priv,
                        optimizer=OptimizerConfig(name="sgd", lr=0.0))
         state = steps_mod.init_train_state(model, rc, jax.random.PRNGKey(0))
         fn = steps_mod._fused_grads if mode == "vmap" else steps_mod._fused_grads_scan
-        with force_impl("perleaf", "dp_noise_tree"):
-            noisy, *_ = fn(model, priv, state.params, batch, 4, keys,
-                           state.noise_state, jnp.float32(1.0), keys.key_clip)
+        noisy, *_ = fn(model, priv, state.params, batch, 4, keys,
+                       state.noise_state, jnp.float32(1.0), keys.key_clip)
         outs[mode] = noisy
-    for a, b in zip(jax.tree.leaves(outs["vmap"]), jax.tree.leaves(outs["scan"])):
+    noise, _ = corrected_noise(outs["scan"], keys.key_xi, state.noise_state,
+                               0.25 * 1.0, 0.0)
+    clipped = jax.tree.map(lambda a, n: a - n, outs["scan"], noise)
+    for a, b in zip(jax.tree.leaves(outs["vmap"]), jax.tree.leaves(clipped)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
